@@ -15,10 +15,16 @@ deprecation shims over the same Session facade.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 from typing import List, Optional
 
+from repro.core import jit_cache
 from repro.launch import cli
+
+#: the checkout holding src/repro: the compilation cache lives beside it
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -106,9 +112,6 @@ def build_parser() -> argparse.ArgumentParser:
     c.add_argument("--smoke", action="store_true",
                    help="enforce each scenario's expectation gates; "
                         "exit 1 if any fail")
-    c.add_argument("--compilation-cache-dir", default="",
-                   help="persistent XLA compilation cache for the live "
-                        "runs (repeat invocations skip re-jit)")
     # recovery flags arm session.run.resilience, which the chaos runner's
     # simulated fleets AND live trainer runs inherit (docs/resilience.md)
     cli.add_resilience_args(c)
@@ -351,6 +354,7 @@ _HANDLERS = {
 
 def main(argv: Optional[List[str]] = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
+    jit_cache.place_compilation_cache(_CHECKOUT)
     if argv[:1] == ["dryrun"]:
         return _cmd_dryrun(argv[1:])
     args = build_parser().parse_args(argv)

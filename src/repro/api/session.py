@@ -28,7 +28,6 @@ from typing import Dict, List, Optional, Tuple
 from repro.configs import ARCH_IDS, RunConfig, get_config
 from repro.configs.base import ModelConfig
 from repro.api.events import EventBus
-from repro.core import jit_cache
 from repro.api.serving import ServeReport, generate
 from repro.core.perf_model.cluster_model import (Eq4Inputs, PSBottleneckModel,
                                                  WorkerSpec, cluster_speed,
@@ -84,9 +83,6 @@ class Session:
         self.run = run or RunConfig()
         self.arch = arch or cfg.name
         self.bus = bus or EventBus()
-        if self.run.compilation_cache_dir:
-            # persistent XLA cache: repeated chaos/live runs skip re-jit
-            jit_cache.enable_persistent_cache(self.run.compilation_cache_dir)
         # session-default transient market; plan/simulate/predict take a
         # per-call `provider=` override (name or FleetProvider instance)
         self.provider: FleetProvider = get_provider(provider)
@@ -629,14 +625,18 @@ class Session:
         return report
 
     # ------------------------------------------------------------- serve
+    @property
+    def params(self):
+        """The exact final weights of the last `train()` (the trainer's
+        checkpoint may lag by up to checkpoint_interval-1 steps), or None
+        before any training: what `serve()` decodes with."""
+        return (self._last_state.params
+                if self._last_state is not None else None)
+
     def serve(self, tokens: int = 16, *, batch: int = 4,
               prompt_len: int = 32, temperature: float = 0.0,
               seed: int = 1) -> ServeReport:
-        # serve the exact final weights of the last train() (the trainer's
-        # checkpoint may lag by up to checkpoint_interval-1 steps)
-        params = (self._last_state.params
-                  if self._last_state is not None else None)
-        report = generate(self.cfg, params, batch=batch,
+        report = generate(self.cfg, self.params, batch=batch,
                           prompt_len=prompt_len, tokens=tokens,
                           temperature=temperature, seed=seed)
         self.bus.emit("serve", arch=report.arch, batch=report.batch,

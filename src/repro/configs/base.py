@@ -230,9 +230,6 @@ class RunConfig:
     grad_compression: str = "none"        # none | bf16 | int8 | topk
     seed: int = 0
     microbatch: int = 0                   # 0 => no gradient accumulation
-    # persistent JAX compilation cache directory ("" = disabled): repeated
-    # Sessions/processes over the same step skip XLA recompilation
-    compilation_cache_dir: str = ""
     # recovery policies (repro.resilience.ResilienceConfig; None = the
     # pre-resilience fail-fast behavior). Steers the outer training loop
     # and the fleet simulators, never the traced step function.

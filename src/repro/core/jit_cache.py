@@ -18,6 +18,7 @@ normalized away by the callers.
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Any, Callable, Dict, Iterable, Tuple, TypeVar
 
 T = TypeVar("T")
@@ -58,39 +59,23 @@ def clear() -> None:
 
 def normalized_run(run) -> object:
     """A RunConfig with the trace-irrelevant fields zeroed, for keying:
-    checkpoint_dir/interval steer the outer loop, seed steers data, the
-    compilation-cache dir steers XLA's disk cache, resilience policies
-    steer retries around the step — none of them reach the jitted step
-    function."""
+    checkpoint_dir/interval steer the outer loop, seed steers data,
+    resilience policies steer retries around the step — none of them
+    reach the jitted step function."""
     return dataclasses.replace(run, checkpoint_dir="",
                                checkpoint_interval=0, seed=0,
-                               compilation_cache_dir="",
                                resilience=None, recalibration=None)
 
 
-_PERSISTENT_DIR = None
+def place_compilation_cache(root: str) -> None:
+    """Keep JAX's persistent compilation cache at `<root>/.jax_cache`.
 
-
-def enable_persistent_cache(path: str) -> bool:
-    """Point JAX's persistent (on-disk) compilation cache at `path`.
-
-    Complements the in-process memo above: that one dedupes within a
-    process, the disk cache survives process restarts — repeated chaos /
-    live runs of the same step skip XLA entirely. Idempotent; returns
-    False (feature off) when this JAX build lacks the config knobs."""
-    global _PERSISTENT_DIR
-    if not path:
-        return False
-    if _PERSISTENT_DIR == path:
-        return True
+    Called by the entry points (`python -m repro`, `chip_smoke.py`), never
+    at import. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself
+    and this leaves it alone. The directory is fixed, not per run: a cache
+    that moves is never hit again."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        # cache every entry: the smoke-sized steps used in chaos/live runs
-        # compile fast and would otherwise fall under the default minimums
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        return False
-    _PERSISTENT_DIR = str(path)
-    return True
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(os.path.abspath(root), ".jax_cache"))

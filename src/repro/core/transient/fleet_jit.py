@@ -6,9 +6,9 @@ but pays NumPy's interpreter tax per round: dozens of temporaries, fancy
 indexing, per-round Python grouping in the draw batchers. This module
 compiles the identical round into a `lax.while_loop` body — trajectory
 state as stacked `(n,)`/`(n, slots)` device arrays, the next-event select
-as a fused masked min+argmin (the Pallas kernel in
-`repro/kernels/event_select.py` on TPU, its XLA reference elsewhere), and
-every draw the engines share pre-materialized on device:
+as an XLA masked min+argmin on every backend (the state is f64, which the
+Pallas `event_select` kernel cannot take), and every draw the engines
+share pre-materialized on device:
 
 * the `(n, slots)` initial-lifetime matrix is `FleetDraws.initial`
   verbatim (chaos hazard transforms already applied on host);
@@ -33,7 +33,7 @@ else, and the host doubles G and re-enters with the carried state — the
 frozen trajectory replays its pending round against the grown pools, so
 results are independent of the paging schedule.
 
-Everything runs under `jax.experimental.enable_x64` with explicit f64
+Everything runs under `jax.enable_x64` with explicit f64
 state regardless of the global `jax_enable_x64` flag, and the math is
 elementwise per trajectory, so results are byte-identical whatever the
 flag or the trajectory sharding (`_shard` splits the trajectory axis
@@ -51,11 +51,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental import enable_x64
+from jax import enable_x64, lax
 
 from repro.core.perf_model.cluster_model import PSBottleneckModel
-from repro.kernels.ops import event_select
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.core.transient.fleet import FleetSim, SimResult
@@ -97,15 +95,19 @@ def _sample_gcp(U, hours, p24, k, lam, raw24, code):
         return lam * (-jnp.log(1.0 - u * raw24)) ** (1.0 / k)
 
     revoked = U[:, 0] < p24
-    cand = inv_cdf(U[:, 1])
-    pending = U[:, 2] >= _diurnal_weight(code, hours + cand) * _ENVELOPE_INV
-    for j in range(1, 16):
+
+    def thin(j, carry):
+        # a loop, not 16 unrolled copies: the f64 math the TPU emulates
+        # would otherwise multiply the program's size and compile time
+        cand, w, pending = carry
         c2 = inv_cdf(U[:, 1 + 2 * j])
-        cand = jnp.where(pending, c2, cand)
-        acc = (U[:, 2 + 2 * j]
-               < _diurnal_weight(code, hours + c2) * _ENVELOPE_INV)
-        pending = pending & ~acc
-    w = _diurnal_weight(code, hours + cand)
+        w2 = _diurnal_weight(code, hours + c2)
+        return (jnp.where(pending, c2, cand), jnp.where(pending, w2, w),
+                pending & ~(U[:, 2 + 2 * j] < w2 * _ENVELOPE_INV))
+
+    zero = jnp.zeros(U.shape[:1], U.dtype)
+    cand, w, pending = lax.fori_loop(
+        0, 16, thin, (zero, zero, jnp.ones(U.shape[:1], bool)))
     cand = jnp.where(pending & (w == 0.0), cand + 4.0, cand)
     return jnp.where(revoked, jnp.minimum(cand, _GCP_CAP_H), jnp.inf)
 
@@ -266,7 +268,10 @@ def _compiled(law_kind: str, handover: bool, graceful: bool,
             ev_all = jnp.concatenate([st["revoke_t"], st["join_t"]],
                                      axis=1)
             ev_all = jnp.where(act[:, None], ev_all, P_INF)
-            ev_t, ev_arg = event_select(ev_all)
+            # next event per trajectory: masked min + lowest-column argmin
+            # in plain XLA (f64 state; Mosaic kernels take no f64 operand)
+            ev_t = jnp.min(ev_all, axis=1)
+            ev_arg = jnp.argmin(ev_all, axis=1).astype(jnp.int32)
             mults, psf, blk = seg_factors(t)
             sp = jnp.minimum(jnp.sum(st["alive"] * mults
                                      * ar["slot_speed"], axis=1),
@@ -581,7 +586,7 @@ def run_jit(sim: "FleetSim", total_steps: int, n: int,
     fn = _compiled(spec_kind, bool(sim.handover), bool(graceful),
                    bool(sim.replace), resilient)
 
-    with enable_x64():
+    with enable_x64(True):
         traj_sh, rep_sh = _shard(n)
         n_dev = len(jax.devices())
         n_pad = n if traj_sh is None else -(-n // n_dev) * n_dev

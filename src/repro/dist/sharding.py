@@ -174,17 +174,6 @@ def tree_shardings(mesh, axes_tree, rules: Rules, specs_tree):
         axes_tree, specs_tree, is_leaf=_is_axes_leaf)
 
 
-def abstract_mesh(axis_sizes: Sequence[int],
-                  axis_names: Sequence[str]):
-    """Version-portable AbstractMesh construction (the constructor signature
-    changed across jax releases)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
-
-
 # ---------------------------------------------------------------------------
 # ambient context for model-internal constraints
 # ---------------------------------------------------------------------------

@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_ROWS = 256
+# XLA lays a 1-D f32/i32 vector out in tiles of 1024, and Mosaic requires
+# each output block to match that layout
+DEFAULT_BLOCK_ROWS = 1024
 
 
 def _event_select_kernel(ev_ref, t_ref, i_ref):

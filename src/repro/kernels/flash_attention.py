@@ -54,12 +54,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
                                                       (block_q, block_k), 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                           # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -67,7 +67,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finalize():
         l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(l_safe)
 
 
@@ -77,7 +77,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 def flash_attention_fwd(q, k, v, *, causal=True, scale=None,
                         block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                         interpret=False):
-    """q:(B,Sq,H,hd) k,v:(B,Sk,KV,hd) -> (out, lse). GQA via head mapping."""
+    """q:(B,Sq,H,hd) k,v:(B,Sk,KV,hd) -> (out, lse (B,H,Sq,1) f32).
+
+    GQA via head mapping. The per-row statistics (lse here, delta in the
+    backward pass) are carried as (…, Sq, 1) columns: a block's last two
+    dimensions must tile (8, 128) or span the array, so (block_q, 1) over
+    (Sq, 1) compiles where a 1-wide slice of a head axis would not."""
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     G = H // KV
@@ -106,17 +111,18 @@ def flash_attention_fwd(q, k, v, *, causal=True, scale=None,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             # VMEM accumulators carried across the sequential ik dimension
             pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)
@@ -146,8 +152,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        lse = lse_ref[0, 0]                              # (bq, 1)
+        delta = delta_ref[0, 0]                          # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
@@ -156,10 +162,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
                                                       (block_q, block_k), 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dq_acc[...] += jax.lax.dot(ds.astype(k.dtype), k,
                                    preferred_element_type=jnp.float32)
 
@@ -190,8 +196,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        lse = lse_ref[0, 0]                                # (bq, 1)
+        delta = delta_ref[0, 0]                            # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
@@ -200,13 +206,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
                                                       (block_q, block_k), 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                      # (bq, bk)
+        p = jnp.exp(s - lse)                               # (bq, bk)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # (bk, hd)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale             # (bq, bk)
+        ds = p * (dp - delta) * scale                      # (bq, bk)
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # (bk, hd)
@@ -236,13 +242,14 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, scale=None,
     vt = v.transpose(0, 2, 1, 3)
     dot = do.transpose(0, 2, 1, 3)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)            # (B,H,Sq)
+                    axis=-1).transpose(0, 2, 1)[..., None]  # (B,H,Sq,1)
 
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k)
     q_spec = pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0))
     kv_spec_q = pl.BlockSpec((1, 1, block_k, hd),
                              lambda b, h, i, j, G=G: (b, h // G, j, 0))
-    lse_spec = pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i))
+    lse_spec = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b, h, i, j: (b, h, i, 0))
 
     dq_t = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, nk=nk, **kw),
@@ -259,7 +266,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, scale=None,
     kv_spec_k = pl.BlockSpec((1, 1, block_k, hd),
                              lambda b, h, j, i, G=G: (b, h // G, j, 0))
     kvh_spec = pl.BlockSpec((1, 1, block_k, hd), lambda b, h, j, i: (b, h, j, 0))
-    lse_spec_k = pl.BlockSpec((1, 1, block_q), lambda b, h, j, i: (b, h, i))
+    lse_spec_k = pl.BlockSpec((1, 1, block_q, 1),
+                              lambda b, h, j, i: (b, h, i, 0))
     dkh_t, dvh_t = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, nq=nq, **kw),
         grid=(B, H, nk, nq),

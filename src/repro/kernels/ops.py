@@ -1,7 +1,8 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On non-TPU backends (this container) kernels run in interpret mode — the
-kernel body executes in Python on CPU, validating the exact TPU program logic.
+On a TPU the kernels compile with Mosaic; on any other backend they run in
+interpret mode, where the kernel body executes as plain JAX ops, validating
+the same program logic.
 Backward passes: flash attention has a full Pallas bwd; ssd/rmsnorm use
 custom_vjp with an XLA bwd over the ref (kernel accelerates fwd, bwd is
 recompute — documented in docs/DESIGN.md §1, kernels layer).
@@ -25,16 +26,13 @@ def _interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# event select: Pallas on TPU, XLA reference elsewhere (interpret-mode Pallas
-# would run the kernel body row-block by row-block in Python — far slower
-# than the fused XLA min/argmin, so CPU/GPU fall back automatically)
+# event select: the Pallas kernel for 32-bit event matrices (the jit fleet
+# engine holds f64 state and reduces it in XLA instead; see fleet_jit)
 # ---------------------------------------------------------------------------
 def event_select(ev):
     """(n, m) candidate-event times, inf = masked -> (min_t (n,), argmin
     (n,) int32), ties broken by lowest column. Not differentiable."""
-    if _interpret():
-        return ref.event_select_ref(ev)
-    return es.event_select_fwd(ev, interpret=False)
+    return es.event_select_fwd(ev, interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------

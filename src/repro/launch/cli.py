@@ -61,9 +61,6 @@ def add_train_args(p: argparse.ArgumentParser,
                    choices=("none", "bf16", "int8", "topk"),
                    help="§VI-B wire compression with error feedback; "
                         "also rescales the predicted PS capacity")
-    p.add_argument("--compilation-cache-dir", default=None,
-                   help="persistent JAX compilation cache directory — "
-                        "repeated runs skip re-jitting identical steps")
 
 
 def add_resilience_args(p: argparse.ArgumentParser) -> None:
@@ -226,7 +223,6 @@ def run_config_from_args(args: argparse.Namespace) -> RunConfig:
         "total_steps": "steps", "checkpoint_interval": "checkpoint_interval",
         "master_weights": "master_weights", "seed": "seed",
         "grad_compression": "grad_compression",
-        "compilation_cache_dir": "compilation_cache_dir",
     }
     for field, attr in mapping.items():
         if field in fields and getattr(args, attr, None) is not None:

@@ -144,17 +144,8 @@ def model_flops(cfg, shape) -> float:
     return 2.0 * n_active * shape.global_batch
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() returns a dict on recent jax and a
-    per-program list on jax<0.5 — normalize to one dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _cost_of(compiled) -> Dict[str, float]:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     coll = collective_stats(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),
@@ -262,7 +253,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     compiled = lowered.compile()
     rec["compile_s"] = round(time.time() - t0, 2)
 
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     rec["flops_per_device"] = float(ca.get("flops", 0.0))
     rec["bytes_per_device"] = float(ca.get("bytes accessed", 0.0))
     try:

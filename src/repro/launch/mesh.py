@@ -10,11 +10,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds a 2-pod outer axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """A mesh whose axes are all `Auto`: the model's `constrain` calls
+    (`dist.sharding.constrain`) only take Auto axes, and `jax.make_mesh`
+    defaults to Explicit ones."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1):
@@ -22,4 +26,4 @@ def make_host_mesh(n_data: int = 1, n_model: int = 1):
     n = len(jax.devices())
     n_data = min(n_data, n)
     n_model = max(1, min(n_model, n // max(1, n_data)))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

@@ -57,6 +57,20 @@ def _reset_by_batch_axis(state, axes, mask):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+def build_step(cfg: ModelConfig, axes):
+    """The jitted gateway iteration: reset joining slots, one decode step
+    through the cache, then per-slot sampling. `axes` is the decode
+    state's axes tree (`api.init_decode_state(...)[1]`)."""
+    def f(params, state, toks, pos, reset, temps, key):
+        state = _reset_by_batch_axis(state, axes, reset)
+        logits, state = api.decode_step(params, cfg, state, toks, pos)
+        greedy = jnp.argmax(logits, -1)
+        safe = jnp.where(temps > 0, temps, 1.0)
+        sampled = jax.random.categorical(key, logits / safe[:, None], -1)
+        return jnp.where(temps > 0, sampled, greedy), state
+    return jax.jit(f)
+
+
 class GatewayEngine:
     """Slot-level continuous batching over one model's decode state."""
 
@@ -84,22 +98,9 @@ class GatewayEngine:
         self._join_mask = np.zeros(slots, bool)     # reset on next step
         self.step_seconds: List[float] = []         # per-iteration wall time
 
-        axes = self._axes
-
-        def build():
-            def f(params, state, toks, pos, reset, temps, key):
-                state = _reset_by_batch_axis(state, axes, reset)
-                logits, state = api.decode_step(params, cfg, state, toks,
-                                                pos)
-                greedy = jnp.argmax(logits, -1)
-                safe = jnp.where(temps > 0, temps, 1.0)
-                sampled = jax.random.categorical(
-                    key, logits / safe[:, None], -1)
-                return jnp.where(temps > 0, sampled, greedy), state
-            return jax.jit(f)
-
-        self._step = jit_cache.cached("serve_step", (cfg, slots, max_len),
-                                      build)
+        self._step = jit_cache.cached(
+            "serve_step", (cfg, slots, max_len),
+            lambda: build_step(cfg, self._axes))
 
     # ----------------------------------------------------------- admission
     def free_slots(self) -> List[int]:

@@ -8,15 +8,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-# Gate the optional `hypothesis` dependency: this container has no network,
-# so when the real package is absent install a minimal deterministic stub
-# (tests/_hypothesis_stub.py) before any test module imports it.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_stub
-    _hypothesis_stub.install()
-
 import pytest
 
 # Tests that took >5 s on the reference box (pytest --durations), tagged

@@ -117,3 +117,26 @@ def test_bench_driver_exit_codes():
     from benchmarks import run as bench_run
     assert bench_run.main(["--list"]) == 0
     assert bench_run.main(["--only", "definitely_not_a_module"]) == 2
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compilation_cache_placed_in_checkout_unless_env_names_one(
+        env_dir, tmp_path, monkeypatch):
+    """Entry points keep JAX's compile cache at `<checkout>/.jax_cache`;
+    where JAX_COMPILATION_CACHE_DIR is set, JAX reads it and no code
+    sets another path."""
+    from repro.core import jit_cache
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jit_cache.place_compilation_cache(str(tmp_path))
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env_dir is None:
+        assert after == str(tmp_path / ".jax_cache")
+    else:
+        assert after == before

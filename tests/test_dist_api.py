@@ -10,11 +10,12 @@ from jax.sharding import PartitionSpec as P
 from repro.dist import sharding as sh
 from repro.dist.compression import ErrorFeedback, compression_ratio
 from repro.dist.elastic import ElasticMembership, Member, split_batch
+from repro.launch.mesh import make_mesh
 
 
 # ------------------------------------------------------------------ sharding
 def test_tree_shardings_roundtrip():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     axes = {"wq": ("embed", "heads", None), "scale": ("embed",),
             "tok": ("batch", "seq")}
     specs = {"wq": jax.ShapeDtypeStruct((8, 4, 2), jnp.float32),
@@ -45,7 +46,7 @@ def test_constrain_identity_outside_context():
 
 
 def test_spec_with_shape_applies_divisibility():
-    am = sh.abstract_mesh((4, 2), ("data", "model"))
+    am = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
     assert sh.spec(("batch", "heads"), sh.MEGATRON_RULES, am,
                    shape=(6, 4)) == P(None, "model")
 

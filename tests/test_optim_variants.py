@@ -8,6 +8,7 @@ import pytest
 from repro.configs import RunConfig, TRAIN_4K, get_config
 from repro.dist import sharding as sh
 from repro.launch import steps as st
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.optim import adamw, cosine_warmup, make_optimizer
 
@@ -58,7 +59,7 @@ def test_master_weights_bits_match_fp32_updates():
     ("dp", sh.DP_RULES), ("ep", sh.EP_RULES), ("dpep", sh.DPEP_RULES),
     ("fsdp", sh.FSDP_RULES)])
 def test_rule_variants_resolve(rules_name, rules):
-    m = jax.make_mesh((1, 1), ("data", "model"))
+    m = make_mesh((1, 1), ("data", "model"))
     spec = sh.logical_spec(("batch", "seq", "embed"), rules, m)
     assert spec is not None
     if rules_name == "dp":
@@ -72,7 +73,7 @@ def test_moe_forward_same_under_rules():
     cfg = get_config("granite-moe-3b-a800m", smoke=True).with_(dtype="float32")
     params, _ = api.init(cfg, jax.random.PRNGKey(0))
     batch = api.make_batch(cfg, TRAIN_4K, batch_override=2, seq_override=32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     outs = []
     for rules in (sh.MEGATRON_RULES, sh.DP_RULES, sh.EP_RULES, sh.DPEP_RULES):
         with sh.use_sharding(mesh, rules):
@@ -92,7 +93,7 @@ def test_cosine_warmup_shape():
 def test_zero1_shards_opt_state():
     cfg = get_config("qwen3-1.7b", smoke=True)
     run = RunConfig(zero1=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ps = st.param_shardings(mesh, cfg)
     os_ = st.opt_shardings(mesh, cfg, run, ps)
     assert set(os_.keys()) == {"m", "v"}

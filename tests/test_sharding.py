@@ -12,12 +12,13 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as sh
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_logical_spec_resolution():
@@ -41,7 +42,7 @@ def test_duplicate_axis_not_reused():
 @given(st.integers(1, 64), st.integers(1, 64))
 @settings(max_examples=40, deadline=None)
 def test_divisible_spec_property(dim0, dim1):
-    m = jax.make_mesh((1, 1), ("data", "model"))
+    m = make_mesh((1, 1), ("data", "model"))
     spec = sh.divisible_spec(m, P("data", "model"), (dim0, dim1))
     # with 1-sized axes everything divides
     assert spec == P("data", "model")
@@ -49,9 +50,8 @@ def test_divisible_spec_property(dim0, dim1):
 
 def test_divisible_spec_drops_indivisible():
     # fake a 4x2 mesh via abstract mesh sizes using the real 1-device mesh is
-    # impossible; emulate with AbstractMesh (sh.abstract_mesh papers over the
-    # constructor-signature change across jax releases)
-    am = sh.abstract_mesh((4, 2), ("data", "model"))
+    # impossible; emulate with AbstractMesh
+    am = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
     spec = sh.divisible_spec(am, P("data", "model"), (6, 4))
     assert spec == P(None, "model")  # 6 % 4 != 0 -> drop data; 4 % 2 == 0
     spec2 = sh.divisible_spec(am, P(("data", "model"),), (8,))
@@ -73,9 +73,10 @@ import jax, jax.numpy as jnp
 from repro.configs import get_config, RunConfig, SHAPES
 from repro.dist import sharding as sh
 from repro.launch import steps as st
+from repro.launch.mesh import make_mesh
 from repro.models import api
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_config("qwen3-1.7b", smoke=True)
 run = RunConfig(zero1=True)
 step, _ = st.make_train_step(cfg, run)
@@ -92,8 +93,6 @@ with sh.use_sharding(mesh, sh.MEGATRON_RULES):
         state_specs, b_specs)
     compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):  # jax<0.5 returns a per-program list
-        ca = ca[0] if ca else {}
     print(json.dumps({"ok": True, "flops": float(ca.get("flops", 0))}))
 """ % ROOT
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
