@@ -267,14 +267,36 @@ def phase_train(cfg, run, ckpt: str):
           f"first loss {rep.losses[0]} is not near ln(vocab) {ln_v}")
     check(rep.checkpoints == 1, f"{rep.checkpoints} checkpoints saved")
     check(s.bus.handler_errors == 0, "event-bus handler raised")
-    peak = peak_bytes(jax.devices()[0])
     report("train", arch=ARCH, layers=cfg.n_layers,
            params=cfg.param_count(), tokens_per_step=BATCH * SEQ,
            losses=rep.losses, init_and_first_step_s=step_s[0],
            step_s=step_s[1:].tolist(),
            tokens_per_s=BATCH * SEQ / float(np.median(step_s[1:])),
            save_s=s.trainer.ckpt.last_save_seconds,
-           peak_bytes_in_use=peak)
+           memory_stats=jax.devices()[0].memory_stats(),
+           **compiled_step(s, cfg, run))
+
+
+def compiled_step(s, cfg, run) -> dict:
+    """The compiler's memory and FLOP counts for the program the trainer
+    ran: its own jitted step, lowered at the batch it was given. The FLOP
+    count takes the body of the layer scan once, whatever the depth (see
+    `models/transformer.py`), so it is below the step's real FLOPs."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.launch import steps as st
+
+    tok = jax.ShapeDtypeStruct((BATCH, SEQ), jnp.int32)
+    compiled = s.trainer._jit_step.lower(
+        st.train_state_specs(cfg, run),
+        {"tokens": tok, "labels": tok}).compile()
+    mem = compiled.memory_analysis()
+    return {"step_flops": compiled.cost_analysis()["flops"],
+            "step_argument_bytes": mem.argument_size_in_bytes,
+            "step_output_bytes": mem.output_size_in_bytes,
+            "step_alias_bytes": mem.alias_size_in_bytes,
+            "step_temp_bytes": mem.temp_size_in_bytes}
 
 
 def phase_resume(cfg, run, ckpt: str):

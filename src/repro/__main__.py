@@ -22,9 +22,12 @@ from typing import List, Optional
 from repro.core import jit_cache
 from repro.launch import cli
 
-#: the checkout holding src/repro: the compilation cache lives beside it
-_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+_SRC = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: the checkout holding src/repro, where the compilation cache lives; None
+#: when the package runs from an installed copy, which names no checkout
+_CHECKOUT = (os.path.dirname(_SRC) if os.path.basename(_SRC) == "src"
+             and os.path.isfile(os.path.join(os.path.dirname(_SRC),
+                                             "pyproject.toml")) else None)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -354,9 +357,12 @@ _HANDLERS = {
 
 def main(argv: Optional[List[str]] = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
-    jit_cache.place_compilation_cache(_CHECKOUT)
     if argv[:1] == ["dryrun"]:
+        # before anything imports jax: launch.dryrun pins the platform to
+        # 512 host CPU devices, which jax reads once, at its import
         return _cmd_dryrun(argv[1:])
+    if _CHECKOUT:
+        jit_cache.place_compilation_cache(_CHECKOUT)
     args = build_parser().parse_args(argv)
     try:
         return _HANDLERS[args.cmd](args)

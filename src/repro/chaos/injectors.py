@@ -37,95 +37,6 @@ import numpy as np
 _TAG_INITIAL = 0xC4A05
 _TAG_JOIN = 0xC4A15
 
-# numpy's SeedSequence hash constants (numpy/random/bit_generator.pyx) and
-# the PCG64 multiplier (numpy/random/src/pcg64/pcg64.h)
-_INIT_A, _MULT_A = 0x43B0D7E5, 0x931E8875
-_INIT_B, _MULT_B = 0x8B51F9DD, 0x58F38DED
-_MIX_L, _MIX_R = 0xCA01F9DD, 0x4973F715
-_PCG_HI, _PCG_LO = 0x2360ED051FC65DA4, 0x4385DF649FCCF645
-_M32 = 0xFFFFFFFF
-
-
-def keyed_uniforms(*key) -> np.ndarray:
-    """`default_rng(SeedSequence(key)).random()` for every element of the
-    broadcast integer arrays `key` at once.
-
-    Each key part must lie in [0, 2**32), so it is one word of the
-    SeedSequence entropy. The hash, the PCG64 seeding and the first
-    double are numpy's own, done in uint32/uint64 array arithmetic
-    (which wraps), so the draws equal the scalar calls bit for bit."""
-    words = [np.asarray(k, np.int64) for k in key]
-    for w in words:
-        if w.size and (w.min() < 0 or w.max() > _M32):
-            raise ValueError("keyed_uniforms takes key parts in [0, 2**32)")
-    words = np.broadcast_arrays(*words)
-    shape = words[0].shape
-    # flat arrays: numpy warns on wrapping scalars, never on arrays
-    words = [w.astype(np.uint32).reshape(-1) for w in words]
-    u32 = np.uint32
-    hc = _INIT_A
-
-    def hashmix(v):
-        nonlocal hc
-        v = v ^ u32(hc)
-        hc = (hc * _MULT_A) & _M32
-        v = v * u32(hc)
-        return v ^ (v >> u32(16))
-
-    def mix(x, y):
-        r = u32(_MIX_L) * x - u32(_MIX_R) * y
-        return r ^ (r >> u32(16))
-
-    zero = np.zeros_like(words[0])
-    pool = [hashmix(words[i] if i < len(words) else zero) for i in range(4)]
-    for s in range(4):
-        for d in range(4):
-            if s != d:
-                pool[d] = mix(pool[d], hashmix(pool[s]))
-    for w in words[4:]:
-        for d in range(4):
-            pool[d] = mix(pool[d], hashmix(w))
-    # generate_state(4, uint64): 8 words cycled from the pool
-    hc = _INIT_B
-    state = []
-    for i in range(8):
-        v = pool[i % 4] ^ u32(hc)
-        hc = (hc * _MULT_B) & _M32
-        v = v * u32(hc)
-        state.append((v ^ (v >> u32(16))).astype(np.uint64))
-    s64 = [state[2 * i] | (state[2 * i + 1] << np.uint64(32))
-           for i in range(4)]
-
-    def mulhi(a, b):                    # high 64 bits of a * b
-        lo32 = np.uint64(_M32)
-        a0, a1 = a & lo32, a >> np.uint64(32)
-        b0, b1 = b & lo32, b >> np.uint64(32)
-        mid = a1 * b0 + ((a0 * b0) >> np.uint64(32))
-        mid2 = a0 * b1 + (mid & lo32)
-        return a1 * b1 + (mid >> np.uint64(32)) + (mid2 >> np.uint64(32))
-
-    def step(hi, lo, inc_hi, inc_lo):   # state * MULT + inc  (mod 2**128)
-        mlo, mhi = np.uint64(_PCG_LO), np.uint64(_PCG_HI)
-        nlo = lo * mlo
-        nhi = mulhi(lo, mlo) + lo * mhi + hi * mlo
-        lo2 = nlo + inc_lo
-        return nhi + inc_hi + (lo2 < nlo).astype(np.uint64), lo2
-
-    # pcg64_set_seed: state = (s64[0], s64[1]), sequence = (s64[2], s64[3])
-    one = np.uint64(1)
-    inc_hi = (s64[2] << one) | (s64[3] >> np.uint64(63))
-    inc_lo = (s64[3] << one) | one
-    hi, lo = inc_hi, inc_lo                       # step from state 0
-    lo2 = lo + s64[1]
-    hi, lo = hi + s64[0] + (lo2 < lo).astype(np.uint64), lo2
-    hi, lo = step(hi, lo, inc_hi, inc_lo)
-    hi, lo = step(hi, lo, inc_hi, inc_lo)         # the first draw's step
-    # XSL-RR output, then the top 53 bits as a double in [0, 1)
-    x, rot = hi ^ lo, hi >> np.uint64(58)
-    out = (x >> rot) | (x << ((np.uint64(64) - rot) & np.uint64(63)))
-    return ((out >> np.uint64(11)).astype(np.float64)
-            * (1.0 / 2 ** 53)).reshape(shape)
-
 
 @dataclasses.dataclass(frozen=True)
 class PreemptionWave:
@@ -311,10 +222,15 @@ class FaultTimeline:
         the `(seed, _TAG_JOIN, fault, traj, slot, gen)` draw
         `transform_joins` makes, pre-materialized so a device-resident
         engine can apply the hazard thinning without host callbacks."""
-        fis = np.array([fi for fi, _ in self.hazards], np.int64)
-        return keyed_uniforms(self.seed, _TAG_JOIN, fis[None, None, :],
-                              np.arange(n)[:, None, None],
-                              np.arange(self.n_slots)[None, :, None], gen)
+        F = len(self.hazards)
+        out = np.empty((n, self.n_slots, F))
+        for k, (fi, _) in enumerate(self.hazards):
+            for tj in range(n):
+                for sl in range(self.n_slots):
+                    out[tj, sl, k] = np.random.default_rng(
+                        np.random.SeedSequence(
+                            (self.seed, _TAG_JOIN, fi, tj, sl, gen))).random()
+        return out
 
     # ------------------------------------------------ hazard transforms
     def _cols(self, region: Optional[str]) -> np.ndarray:
@@ -374,7 +290,11 @@ class FaultTimeline:
             rows = cols[slots]
             if not rows.any():
                 continue
-            U = keyed_uniforms(self.seed, _TAG_JOIN, fi, trajs, slots, gens)
+            U = np.array([
+                np.random.default_rng(np.random.SeedSequence(
+                    (self.seed, _TAG_JOIN, fi, int(tj), int(sl), int(g))
+                )).random()
+                for tj, sl, g in zip(trajs, slots, gens)])
             new = self._apply_hazard(lt, U, f, h0)
             lt = np.where(rows, new, lt)
         return lt

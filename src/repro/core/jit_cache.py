@@ -73,7 +73,9 @@ def place_compilation_cache(root: str) -> None:
     Called by the entry points (`python -m repro`, `chip_smoke.py`), never
     at import. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself
     and this leaves it alone. The directory is fixed, not per run: a cache
-    that moves is never hit again."""
+    that moves is never hit again. JAX's default thresholds decide what is
+    written there: compiles faster than `jax_persistent_cache_min_compile_
+    time_secs` (1 s) are not kept."""
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     import jax

@@ -140,3 +140,37 @@ def test_compilation_cache_placed_in_checkout_unless_env_names_one(
         assert after == str(tmp_path / ".jax_cache")
     else:
         assert after == before
+
+
+def test_cli_dryrun_pins_cpu_before_jax_is_imported():
+    """`python -m repro dryrun` hands over to launch.dryrun before anything
+    imports jax, so its CPU platform and 512 host devices hold even where
+    JAX_PLATFORMS is unset (a TPU host). The cell is one valid_cells skips,
+    so nothing compiles."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    code = ("import json, sys\n"
+            "from repro.__main__ import main\n"
+            "rc = main(['dryrun', '--arch', 'qwen3-1.7b', "
+            "'--shape', 'long_500k'])\n"
+            "import jax\n"
+            "print(json.dumps({'rc': rc, "
+            "'platforms': jax.config.jax_platforms, "
+            "'backend': jax.default_backend(), "
+            "'devices': jax.device_count()}))\n")
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [src] + [p for p in [env.get("PYTHONPATH")] if p])
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = out.stdout.strip().splitlines()
+    assert json.loads(lines[0])["skipped"] is True
+    assert json.loads(lines[-1]) == {"rc": 0, "platforms": "cpu",
+                                     "backend": "cpu", "devices": 512}

@@ -14,7 +14,6 @@ from repro.chaos import (CheckpointOutage, FaultTimeline, LiveFault,
                          Scenario, StragglerFault, get_scenario,
                          list_scenarios, register_scenario, run_scenario,
                          score_history)
-from repro.chaos.injectors import keyed_uniforms
 from repro.chaos.runner import _run_sim
 from repro.core.transient.fleet import FleetSim, SimWorker
 
@@ -130,23 +129,6 @@ def test_join_transform_independent_of_batch_grouping():
                            gens[i:i + 1], hours[i:i + 1])[0]
         for i in range(4)])
     np.testing.assert_array_equal(batch, single)
-
-
-@pytest.mark.parametrize("n_words", [1, 3, 4, 6, 9])
-def test_keyed_uniforms_equal_numpy_seedsequence_draws(n_words):
-    """The array form of the keyed draws is numpy's scalar stream, bit for
-    bit: key lengths below, at and above the SeedSequence pool size, with
-    zeros and words near 2**32."""
-    rng = np.random.default_rng(n_words)
-    keys = rng.integers(0, 2 ** 32, (200, n_words), dtype=np.int64)
-    keys[::3, 0] = 0
-    keys[1::5, -1] = 2 ** 32 - 1
-    got = keyed_uniforms(*keys.T)
-    want = [np.random.default_rng(np.random.SeedSequence(
-        tuple(int(w) for w in k))).random() for k in keys]
-    np.testing.assert_array_equal(got, want)
-    with pytest.raises(ValueError, match="2\\*\\*32"):
-        keyed_uniforms(2 ** 32, 1)
 
 
 # ------------------------------------------------- engine parity
