@@ -18,6 +18,11 @@ Properties the paper's transient setting needs:
   * async mode: device->host copy happens synchronously (fast), file write
     happens on a background thread (training continues) — used to contrast
     with the paper's sequential checkpointing measurement.
+
+Saves and restores run inside `jax.profiler` spans, on whichever thread
+does the work: `ckpt.copy` (device to host), `ckpt.write` (stat `bytes`,
+one `ckpt.crc32` per leaf inside it), `ckpt.commit` (renames, `LATEST`,
+pruning); `ckpt.read` and `ckpt.validate` on restore.
 """
 from __future__ import annotations
 
@@ -32,6 +37,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class CheckpointCorruptError(RuntimeError):
@@ -148,7 +154,8 @@ class Checkpointer:
             if not self.lease.try_acquire():
                 return None  # someone else holds the writer role
         t0 = time.monotonic()
-        flat = _flatten(tree)  # device->host copy is synchronous
+        with TraceAnnotation("ckpt.copy"):
+            flat = _flatten(tree)  # device->host copy is synchronous
         if self.async_write:
             self.wait()
             self._thread = threading.Thread(
@@ -164,46 +171,50 @@ class Checkpointer:
                metadata: dict, fenced: bool = False) -> CheckpointSizes:
         tmp = os.path.join(self.root, f".tmp_step_{step}")
         final = os.path.join(self.root, f"step_{step}")
-        shutil.rmtree(tmp, ignore_errors=True)
-        os.makedirs(tmp, exist_ok=True)
-        index: Dict[str, Any] = {}
-        offset = 0
-        data_path = os.path.join(tmp, "data-00000.bin")
-        with open(data_path, "wb") as f:
-            for key in sorted(flat):
-                arr = flat[key]
-                buf = arr.tobytes()
-                index[key] = {"offset": offset, "nbytes": len(buf),
-                              "shape": list(arr.shape),
-                              "dtype": str(arr.dtype),
-                              "crc": zlib.crc32(buf) & 0xFFFFFFFF}
-                f.write(buf)
-                offset += len(buf)
-        with open(os.path.join(tmp, "index.json"), "w") as f:
-            json.dump(index, f)
-        meta = {"step": step, "n_tensors": len(flat),
-                "created": time.time(), **metadata}
-        with open(os.path.join(tmp, "meta.json"), "w") as f:
-            json.dump(meta, f)
-        if fenced and not self.lease.held_by_me():
-            # the lease was stolen (holder revoked mid-save): abort before
-            # the rename so the contested write never becomes visible
+        with TraceAnnotation("ckpt.write") as span:
             shutil.rmtree(tmp, ignore_errors=True)
-            raise LeaseLostError(
-                f"{self.lease.holder} lost writer.lease during save of "
-                f"step {step}; commit aborted")
-        shutil.rmtree(final, ignore_errors=True)
-        os.replace(tmp, final)
-        with open(os.path.join(self.root, "LATEST.tmp"), "w") as f:
-            f.write(str(step))
-        os.replace(os.path.join(self.root, "LATEST.tmp"),
-                   os.path.join(self.root, "LATEST"))
-        sizes = CheckpointSizes(
-            offset,
-            os.path.getsize(os.path.join(final, "index.json")),
-            os.path.getsize(os.path.join(final, "meta.json")))
-        self.last_sizes = sizes
-        self._gc()
+            os.makedirs(tmp, exist_ok=True)
+            index: Dict[str, Any] = {}
+            offset = 0
+            data_path = os.path.join(tmp, "data-00000.bin")
+            with open(data_path, "wb") as f:
+                for key in sorted(flat):
+                    arr = flat[key]
+                    buf = arr.tobytes()
+                    with TraceAnnotation("ckpt.crc32"):
+                        crc = zlib.crc32(buf) & 0xFFFFFFFF
+                    index[key] = {"offset": offset, "nbytes": len(buf),
+                                  "shape": list(arr.shape),
+                                  "dtype": str(arr.dtype), "crc": crc}
+                    f.write(buf)
+                    offset += len(buf)
+            with open(os.path.join(tmp, "index.json"), "w") as f:
+                json.dump(index, f)
+            meta = {"step": step, "n_tensors": len(flat),
+                    "created": time.time(), **metadata}
+            with open(os.path.join(tmp, "meta.json"), "w") as f:
+                json.dump(meta, f)
+            sizes = CheckpointSizes(
+                offset, os.path.getsize(os.path.join(tmp, "index.json")),
+                os.path.getsize(os.path.join(tmp, "meta.json")))
+            span.set_metadata(bytes=sizes.total)
+        with TraceAnnotation("ckpt.commit"):
+            if fenced and not self.lease.held_by_me():
+                # the lease was stolen (holder revoked mid-save): abort
+                # before the rename so the contested write never becomes
+                # visible
+                shutil.rmtree(tmp, ignore_errors=True)
+                raise LeaseLostError(
+                    f"{self.lease.holder} lost writer.lease during save of "
+                    f"step {step}; commit aborted")
+            shutil.rmtree(final, ignore_errors=True)
+            os.replace(tmp, final)
+            with open(os.path.join(self.root, "LATEST.tmp"), "w") as f:
+                f.write(str(step))
+            os.replace(os.path.join(self.root, "LATEST.tmp"),
+                       os.path.join(self.root, "LATEST"))
+            self.last_sizes = sizes
+            self._gc()
         return sizes
 
     def wait(self) -> None:
@@ -261,28 +272,29 @@ class Checkpointer:
         step = step if step is not None else self.latest_step()
         if step is None:
             raise FileNotFoundError(f"no checkpoint under {self.root}")
-        d = os.path.join(self.root, f"step_{step}")
-        with open(os.path.join(d, "index.json")) as f:
-            index = json.load(f)
-        with open(os.path.join(d, "data-00000.bin"), "rb") as f:
-            blob = f.read()
-        flat = {}
-        for key, rec in index.items():
-            arr = np.frombuffer(
-                blob, dtype=np.dtype(rec["dtype"]),
-                count=int(np.prod(rec["shape"])) if rec["shape"] else 1,
-                offset=rec["offset"]).reshape(rec["shape"])
-            flat[key] = arr
-        # rebuild in tree_like's structure
-        leaves_paths = jax.tree_util.tree_flatten_with_path(tree_like)
-        new_leaves = []
-        for path, leaf in leaves_paths[0]:
-            key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                           for p in path)
-            arr = flat[key]
-            new_leaves.append(np.asarray(arr).astype(leaf.dtype)
-                              if hasattr(leaf, "dtype") else arr)
-        tree = jax.tree_util.tree_unflatten(leaves_paths[1], new_leaves)
+        with TraceAnnotation("ckpt.read"):
+            d = os.path.join(self.root, f"step_{step}")
+            with open(os.path.join(d, "index.json")) as f:
+                index = json.load(f)
+            with open(os.path.join(d, "data-00000.bin"), "rb") as f:
+                blob = f.read()
+            flat = {}
+            for key, rec in index.items():
+                arr = np.frombuffer(
+                    blob, dtype=np.dtype(rec["dtype"]),
+                    count=int(np.prod(rec["shape"])) if rec["shape"] else 1,
+                    offset=rec["offset"]).reshape(rec["shape"])
+                flat[key] = arr
+            # rebuild in tree_like's structure
+            leaves_paths = jax.tree_util.tree_flatten_with_path(tree_like)
+            new_leaves = []
+            for path, leaf in leaves_paths[0]:
+                key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
+                               for p in path)
+                arr = flat[key]
+                new_leaves.append(np.asarray(arr).astype(leaf.dtype)
+                                  if hasattr(leaf, "dtype") else arr)
+            tree = jax.tree_util.tree_unflatten(leaves_paths[1], new_leaves)
         return tree, step
 
     # ------------------------------------------------------------- integrity
@@ -292,30 +304,31 @@ class Checkpointer:
         payload covers every recorded extent, and each array's crc32
         matches (entries written before checksums existed get the extent
         check only)."""
-        d = os.path.join(self.root, f"step_{step}")
-        try:
-            with open(os.path.join(d, "index.json")) as f:
-                index = json.load(f)
-            with open(os.path.join(d, "meta.json")) as f:
-                json.load(f)
-            with open(os.path.join(d, "data-00000.bin"), "rb") as f:
-                blob = f.read()
-        except (FileNotFoundError, NotADirectoryError,
-                json.JSONDecodeError) as exc:
-            raise CheckpointCorruptError(
-                f"step {step}: unreadable checkpoint ({exc})") from exc
-        for key, rec in index.items():
-            end = rec["offset"] + rec["nbytes"]
-            if end > len(blob):
+        with TraceAnnotation("ckpt.validate"):
+            d = os.path.join(self.root, f"step_{step}")
+            try:
+                with open(os.path.join(d, "index.json")) as f:
+                    index = json.load(f)
+                with open(os.path.join(d, "meta.json")) as f:
+                    json.load(f)
+                with open(os.path.join(d, "data-00000.bin"), "rb") as f:
+                    blob = f.read()
+            except (FileNotFoundError, NotADirectoryError,
+                    json.JSONDecodeError) as exc:
                 raise CheckpointCorruptError(
-                    f"step {step}: torn payload — {key} needs bytes "
-                    f"[{rec['offset']}, {end}) of {len(blob)}")
-            if "crc" in rec:
-                got = zlib.crc32(blob[rec["offset"]:end]) & 0xFFFFFFFF
-                if got != rec["crc"]:
+                    f"step {step}: unreadable checkpoint ({exc})") from exc
+            for key, rec in index.items():
+                end = rec["offset"] + rec["nbytes"]
+                if end > len(blob):
                     raise CheckpointCorruptError(
-                        f"step {step}: checksum mismatch on {key} "
-                        f"(stored {rec['crc']:#010x}, got {got:#010x})")
+                        f"step {step}: torn payload — {key} needs bytes "
+                        f"[{rec['offset']}, {end}) of {len(blob)}")
+                if "crc" in rec:
+                    got = zlib.crc32(blob[rec["offset"]:end]) & 0xFFFFFFFF
+                    if got != rec["crc"]:
+                        raise CheckpointCorruptError(
+                            f"step {step}: checksum mismatch on {key} "
+                            f"(stored {rec['crc']:#010x}, got {got:#010x})")
 
     def restore_latest_valid(self, tree_like,
                              on_fallback=None) -> Tuple[Any, int, int]:

@@ -1,10 +1,14 @@
 """CM-DARE performance profiler (Fig 1): tracks steps/sec with warmup
 discard, rolling averages, coefficient of variation — feeds the controller's
 bottleneck detector and retrains the online prediction models.
+
+`trace_gc` puts Python's garbage collections into a `jax.profiler` trace,
+beside the program's own spans (docs/performance.md).
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from collections import deque
 from typing import Deque, List, Optional, Tuple
@@ -93,3 +97,34 @@ class PerformanceProfiler:
             return None
         span = rs[-1].t - rs[0].t
         return (rs[-1].step - rs[0].step) / span if span > 0 else None
+
+
+_gc_span = None
+_TraceAnnotation = None
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    # collections neither nest nor overlap (they hold the GIL), so one
+    # open span at a time suffices, whichever thread collects
+    global _gc_span
+    if phase == "start":
+        _gc_span = _TraceAnnotation("python.gc",
+                                    generation=info["generation"])
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        _gc_span.set_metadata(collected=info["collected"])
+        _gc_span.__exit__(None, None, None)
+        _gc_span = None
+
+
+def trace_gc() -> None:
+    """Write each garbage collection as a `python.gc` span (stats
+    `generation` and `collected`) into any running profiler trace.
+    Registers its `gc.callbacks` hook once, however often it is called;
+    a process that never calls it pays nothing."""
+    global _TraceAnnotation
+    # bound here: a collection may start while a module is importing
+    from jax.profiler import TraceAnnotation
+    _TraceAnnotation = TraceAnnotation
+    if _gc_callback not in gc.callbacks:
+        gc.callbacks.append(_gc_callback)

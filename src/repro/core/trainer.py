@@ -11,6 +11,11 @@ Loop contract per step:
   3. jit'd train_step;
   4. profiler.record; controller.check on a cadence;
   5. checkpoint on the interval (writer-lease holder only).
+
+Steps 2-4 run inside `jax.profiler` spans (`train.step` holding
+`train.data`, `train.dispatch`, `train.sync`, `train.observe`), which a
+profile of the run shows beside the device operations; the names are
+listed in docs/performance.md.
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import Checkpointer
 from repro.checkpoint.checkpointer import CheckpointCorruptError
@@ -201,8 +207,9 @@ class TransientTrainer:
                 state, step = self.ckpt.restore(legacy)
                 residual = jax.tree.map(
                     lambda s: jnp.zeros(s.shape, s.dtype), shapes.residual)
-            state = jax.tree.map(jnp.asarray, state)
-            residual = jax.tree.map(jnp.asarray, residual)
+            with TraceAnnotation("ckpt.put"):     # host to device
+                state = jax.tree.map(jnp.asarray, state)
+                residual = jax.tree.map(jnp.asarray, residual)
             self.loader.step = step
             self.restores += 1
             self._emit("restore", {"step": step, "restores": self.restores})
@@ -315,59 +322,33 @@ class TransientTrainer:
                               * self.resilience.degradation.shrink_factor)))
             else:
                 self.loader.global_batch = base_global_batch
-            # 2. data (global batch stays constant across membership changes)
-            n_shards = max(1, self.members.n_alive)
-            batch_np = self.loader.next_global(n_shards)
-            batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-            # 3. step
-            state, metrics = self._jit_step(state, batch)
-            steps_run += 1
-            loss = float(metrics["loss"])
-            losses.append(loss)
-            payload = {"step": step, "loss": loss}
-            if "payload_bytes" in metrics:
-                # §VI-B telemetry: the actual compressed wire size of this
-                # step's gradient push, not a config echo
-                payload["payload_bytes"] = float(metrics["payload_bytes"])
-                payload["grad_compression"] = self.run.grad_compression
-            self._emit("step", payload)
-            # 4. profile + detect (+ §VI-B mitigation). With an injected
-            # clock (chaos), the "step" emit above let the driver advance
-            # virtual time for this step before it is recorded.
-            self.profiler.record(
-                step, t=self.clock() if self.clock is not None else None,
-                loss=loss)
-            if self.predicted_speed and step % check_every == 0 and step > 0:
-                det = self.controller.check(self.profiler,
-                                            self.predicted_speed,
-                                            ps_model=self.ps_model,
-                                            workers=self.workers)
-                self.detections.append(det)
-                self._emit("detection", {"step": step,
-                                         "bottleneck": det.bottleneck,
-                                         "action": det.action.value,
-                                         "deviation": det.deviation,
-                                         "model_version": det.model_version})
-                mitigated = False
-                if self.auto_mitigate and det.action in (
-                        Action.ADD_PARAMETER_SERVER,
-                        Action.ENABLE_COMPRESSION) \
-                        and len(self.mitigations) < self.max_mitigations:
-                    state = self.apply_mitigation(det.action, state,
-                                                  step=step)
-                    mitigated = True
-                if self.recalibrator is not None:
-                    if mitigated:
-                        # mitigation changed the cluster; deviation against
-                        # the pre-mitigation prediction is void drift input
-                        self.recalibrator.notify_mitigation(step)
-                    else:
-                        dev = (det.deviation if det.measured is not None
-                               else None)
-                        new_speed = self.recalibrator.observe(
-                            step, dev, self.profiler)
-                        if new_speed is not None:
-                            self._apply_refit(new_speed, step)
+            # 2-4. one step, in profiler spans (docs/performance.md)
+            with TraceAnnotation("train.step"):
+                # 2. data (global batch stays constant across membership
+                # changes)
+                with TraceAnnotation("train.data") as data:
+                    n_shards = max(1, self.members.n_alive)
+                    batch_np = self.loader.next_global(n_shards)
+                    batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+                    data.set_metadata(tokens=int(batch_np["labels"].size))
+                # 3. step: enqueue, then wait on the device for the loss
+                with TraceAnnotation("train.dispatch"):
+                    state, metrics = self._jit_step(state, batch)
+                steps_run += 1
+                with TraceAnnotation("train.sync"):
+                    loss = float(metrics["loss"])
+                    payload = {"step": step, "loss": loss}
+                    if "payload_bytes" in metrics:
+                        # §VI-B telemetry: the actual compressed wire size
+                        # of this step's gradient push, not a config echo
+                        payload["payload_bytes"] = float(
+                            metrics["payload_bytes"])
+                        payload["grad_compression"] = \
+                            self.run.grad_compression
+                losses.append(loss)
+                # 4. profile + detect (+ §VI-B mitigation)
+                with TraceAnnotation("train.observe"):
+                    state = self._observe(state, step, payload, check_every)
             # 5. checkpoint
             if self.run.checkpoint_interval and \
                     (step + 1) % self.run.checkpoint_interval == 0:
@@ -390,6 +371,47 @@ class TransientTrainer:
             refits=(list(self.recalibrator.refits)
                     if self.recalibrator is not None else []))
         return state, report
+
+    def _observe(self, state: st.TrainState, step: int, payload: dict,
+                 check_every: int) -> st.TrainState:
+        """Emit the step, record it, and on the check cadence run the
+        controller with its mitigation and recalibration. With an injected
+        clock (chaos), the "step" emit lets the driver advance virtual
+        time for this step before it is recorded."""
+        self._emit("step", payload)
+        self.profiler.record(
+            step, t=self.clock() if self.clock is not None else None,
+            loss=payload["loss"])
+        if not (self.predicted_speed and step % check_every == 0
+                and step > 0):
+            return state
+        det = self.controller.check(self.profiler, self.predicted_speed,
+                                    ps_model=self.ps_model,
+                                    workers=self.workers)
+        self.detections.append(det)
+        self._emit("detection", {"step": step,
+                                 "bottleneck": det.bottleneck,
+                                 "action": det.action.value,
+                                 "deviation": det.deviation,
+                                 "model_version": det.model_version})
+        mitigated = False
+        if self.auto_mitigate and det.action in (
+                Action.ADD_PARAMETER_SERVER, Action.ENABLE_COMPRESSION) \
+                and len(self.mitigations) < self.max_mitigations:
+            state = self.apply_mitigation(det.action, state, step=step)
+            mitigated = True
+        if self.recalibrator is not None:
+            if mitigated:
+                # mitigation changed the cluster; deviation against the
+                # pre-mitigation prediction is void drift input
+                self.recalibrator.notify_mitigation(step)
+            else:
+                dev = det.deviation if det.measured is not None else None
+                new_speed = self.recalibrator.observe(step, dev,
+                                                      self.profiler)
+                if new_speed is not None:
+                    self._apply_refit(new_speed, step)
+        return state
 
     def _join_member(self, ev: "MembershipEvent"):
         """Replacement join, retried under the resilience policy: a join

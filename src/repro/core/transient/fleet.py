@@ -12,6 +12,7 @@ import math
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.perf_model.cluster_model import (PSBottleneckModel, WorkerSpec,
                                                  cluster_speed)
@@ -533,6 +534,10 @@ class FleetSim:
 
         `run(...)` with the same seed remains the single-trajectory path;
         `run_many` never perturbs its streams.
+
+        The call runs in a `fleet.run_many` profiler span (stat `n`) that
+        holds `fleet.draws`, the jit engine's own spans and `fleet.results`
+        (docs/performance.md).
         """
         from repro.core.transient.fleet_batched import FleetDraws, run_batched
         if n < 1:
@@ -540,27 +545,30 @@ class FleetSim:
         if engine not in ("batched", "event", "jit"):
             raise ValueError(f"unknown engine {engine!r}; "
                              f"known: ('batched', 'event', 'jit')")
-        draws = FleetDraws(self, n, start_hour)
-        if engine == "batched":
-            results = run_batched(self, total_steps, n, max_hours,
+        with TraceAnnotation("fleet.run_many", n=n):
+            with TraceAnnotation("fleet.draws"):
+                draws = FleetDraws(self, n, start_hour)
+            if engine == "batched":
+                results = run_batched(self, total_steps, n, max_hours,
+                                      start_hour, draws=draws)
+            elif engine == "jit":
+                from repro.core.transient.fleet_jit import run_jit
+                results = run_jit(self, total_steps, n, max_hours,
                                   start_hour, draws=draws)
-        elif engine == "jit":
-            from repro.core.transient.fleet_jit import run_jit
-            results = run_jit(self, total_steps, n, max_hours,
-                              start_hour, draws=draws)
-        else:
-            results = []
-            for j in range(n):
-                sim = self._respawn(self.seed + 1 + 4 * j)
-                results.append(sim.run(total_steps, max_hours, start_hour,
-                                       initial_lifetimes=draws.initial[j],
-                                       draws=draws, traj=j))
-        regions = {r.region for r in results}
-        return FleetEnsemble(results,
-                             SimStats.from_results(results, total_steps),
-                             provider=self.provider.name,
-                             region=regions.pop() if len(regions) == 1
-                             else "")
+            else:
+                results = []
+                for j in range(n):
+                    sim = self._respawn(self.seed + 1 + 4 * j)
+                    results.append(sim.run(
+                        total_steps, max_hours, start_hour,
+                        initial_lifetimes=draws.initial[j], draws=draws,
+                        traj=j))
+            with TraceAnnotation("fleet.results"):
+                regions = {r.region for r in results}
+                return FleetEnsemble(
+                    results, SimStats.from_results(results, total_steps),
+                    provider=self.provider.name,
+                    region=regions.pop() if len(regions) == 1 else "")
 
 
 #: Long-form alias used by the docs and the provider layer.

@@ -52,6 +52,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import enable_x64, lax
+from jax.profiler import TraceAnnotation
 
 from repro.core.perf_model.cluster_model import PSBottleneckModel
 
@@ -486,7 +487,8 @@ def _put(x, sharding, axis=0):
         sharding.mesh, PartitionSpec(*spec)))
 
 
-def _pools(draws: "FleetDraws", G: int, has_chaos: bool, res=None):
+def _pools(draws: "FleetDraws", G: int, has_chaos: bool, res=None,
+           sharding=None):
     """FleetDraws generation levels 1..G as device arrays in the folded
     `(level * S + slot, trajectory, ...)` layout the body's single
     `take_along_axis` per pool expects. Cached on the draws object — the
@@ -495,34 +497,36 @@ def _pools(draws: "FleetDraws", G: int, has_chaos: bool, res=None):
     copies. With a `ResilienceConfig` the restore-retry stall levels
     ride along, indexed by the revoked occupant's generation (0..G-1 —
     level paging freezes any revoke whose occupant reached G before it
-    mutates state, so the index never pages off the pool)."""
-    key = (G, bool(has_chaos), res)
+    mutates state, so the index never pages off the pool). A build
+    runs in a `fleet.pools` span whose `draws` stat counts the chaos
+    join uniforms drawn, one keyed generator each."""
+    key = (G, bool(has_chaos), res, sharding)
     cache = draws.__dict__.setdefault("_jit_pool_cache", {})
     if key in cache:
         return cache[key]
     n, S, K = draws.n, draws.n_slots, draws._K
-    delays = np.empty((G, S, n))
-    uniforms = np.empty((G, S, n, K))
-    for g in range(1, G + 1):
-        d, u = draws._level(g)
-        delays[g - 1] = d.T
-        uniforms[g - 1] = np.swapaxes(u, 0, 1)
-    out = {"delays": jnp.asarray(delays.reshape(G * S, n)),
-           "uniforms": jnp.asarray(uniforms.reshape(G * S, n, K))}
-    if res is not None:
-        stalls = np.empty((G, S, n))
-        for g in range(G):
-            stalls[g] = draws.restore_stall_level(res, g).T
-        out["stalls"] = jnp.asarray(stalls.reshape(G * S, n))
-    if has_chaos:
-        F = len(draws.chaos.hazards)
-        ju = np.empty((G, S, n, F))
+    F = len(draws.chaos.hazards) if has_chaos else 0
+    with TraceAnnotation("fleet.pools", levels=G, draws=G * S * n * F):
+        delays = np.empty((G, S, n))
+        uniforms = np.empty((G, S, n, K))
         for g in range(1, G + 1):
-            ju[g - 1] = np.swapaxes(
-                draws.chaos.join_uniform_matrix(n, g), 0, 1)
-        out["join_U"] = jnp.asarray(ju.reshape(G * S, n, F))
-    else:
-        out["join_U"] = jnp.zeros((G * S, n, 0))
+            d, u = draws._level(g)
+            delays[g - 1] = d.T
+            uniforms[g - 1] = np.swapaxes(u, 0, 1)
+        out = {"delays": delays.reshape(G * S, n),
+               "uniforms": uniforms.reshape(G * S, n, K)}
+        if res is not None:
+            stalls = np.empty((G, S, n))
+            for g in range(G):
+                stalls[g] = draws.restore_stall_level(res, g).T
+            out["stalls"] = stalls.reshape(G * S, n)
+        ju = np.empty((G, S, n, F))
+        if F:
+            for g in range(1, G + 1):
+                ju[g - 1] = np.swapaxes(
+                    draws.chaos.join_uniform_matrix(n, g), 0, 1)
+        out["join_U"] = ju.reshape(G * S, n, F)
+        out = {name: _put(arr, sharding) for name, arr in out.items()}
     cache.clear()            # keep at most one (the deepest) G resident
     cache[key] = out
     return out
@@ -566,94 +570,97 @@ def run_jit(sim: "FleetSim", total_steps: int, n: int,
 
     if n < 1:
         raise ValueError(f"need at least one trajectory, got {n}")
-    spec_kind, law_arrays = _law_spec(sim)
-    if draws is None:
-        draws = FleetDraws(sim, n, start_hour)
-    roster = sim._roster
-    S = len(roster)
-    slot_speed = np.array([speed for _, _, _, speed in roster], float)
-    cap = PSBottleneckModel(sim.model_bytes, sim.n_ps,
-                            n_tensors=sim.n_tensors,
-                            compression=sim.grad_compression
-                            ).capacity_steps_per_s()
-    chaos = getattr(sim, "chaos", None)
-    has_chaos = chaos is not None
-    has_haz = has_chaos and len(chaos.hazards) > 0
-    graceful = (sim.provider.graceful_checkpoint_on_warning
-                and sim.provider.warning_seconds >= sim.t_c)
-    resil = getattr(sim, "resilience", None)
-    resilient = resil is not None
-    fn = _compiled(spec_kind, bool(sim.handover), bool(graceful),
-                   bool(sim.replace), resilient)
-
     with enable_x64(True):
-        traj_sh, rep_sh = _shard(n)
-        n_dev = len(jax.devices())
-        n_pad = n if traj_sh is None else -(-n // n_dev) * n_dev
+        # the roster's law, fault tables and trajectory state, built and
+        # put on the device
+        with TraceAnnotation("fleet.setup"):
+            spec_kind, law_arrays = _law_spec(sim)
+            if draws is None:
+                with TraceAnnotation("fleet.draws"):
+                    draws = FleetDraws(sim, n, start_hour)
+            roster = sim._roster
+            S = len(roster)
+            slot_speed = np.array([speed for _, _, _, speed in roster], float)
+            cap = PSBottleneckModel(sim.model_bytes, sim.n_ps,
+                                    n_tensors=sim.n_tensors,
+                                    compression=sim.grad_compression
+                                    ).capacity_steps_per_s()
+            chaos = getattr(sim, "chaos", None)
+            has_chaos = chaos is not None
+            has_haz = has_chaos and len(chaos.hazards) > 0
+            graceful = (sim.provider.graceful_checkpoint_on_warning
+                        and sim.provider.warning_seconds >= sim.t_c)
+            resil = getattr(sim, "resilience", None)
+            resilient = resil is not None
+            fn = _compiled(spec_kind, bool(sim.handover), bool(graceful),
+                           bool(sim.replace), resilient)
+            traj_sh, rep_sh = _shard(n)
+            n_dev = len(jax.devices())
+            n_pad = n if traj_sh is None else -(-n // n_dev) * n_dev
 
-        if has_chaos:
-            bounds, sp_tab, ps_tab, blk_tab = chaos.factor_tables()
-            hz_s, hz_e, hz_r, hz_c = chaos.hazard_tables()
-        else:
-            bounds = np.zeros(0)
-            sp_tab, ps_tab = np.ones((1, S)), np.ones(1)
-            blk_tab = np.zeros(1, bool)
-            hz_s = hz_e = hz_r = np.zeros(0)
-            hz_c = np.zeros((0, S), bool)
-        ar = {"slot_speed": _put(slot_speed, rep_sh),
-              "cap": jnp.asarray(float(cap)),
-              "i_c": jnp.asarray(float(sim.i_c)),
-              "t_c": jnp.asarray(float(sim.t_c)),
-              "total": jnp.asarray(float(total_steps)),
-              "tmax": jnp.asarray(max_hours * 3600.0),
-              "start_hour": jnp.asarray(float(start_hour)),
-              "boundaries": _put(bounds, rep_sh),
-              "speed_table": _put(sp_tab, rep_sh),
-              "ps_table": _put(ps_tab, rep_sh),
-              "blk_table": _put(blk_tab, rep_sh),
-              "hz_start": _put(hz_s, rep_sh),
-              "hz_end": _put(hz_e, rep_sh),
-              "hz_rate": _put(hz_r, rep_sh),
-              "hz_cols": _put(hz_c, rep_sh)}
-        if resilient:
-            ar["quorum"] = jnp.asarray(float(resil.degradation.quorum))
-            ar["shrink_below"] = jnp.asarray(
-                float(resil.degradation.shrink_below))
-            ar["shrink_factor"] = jnp.asarray(
-                float(resil.degradation.shrink_factor))
-        for name, arr in law_arrays.items():
-            ar[name] = _put(arr, rep_sh)
+            if has_chaos:
+                bounds, sp_tab, ps_tab, blk_tab = chaos.factor_tables()
+                hz_s, hz_e, hz_r, hz_c = chaos.hazard_tables()
+            else:
+                bounds = np.zeros(0)
+                sp_tab, ps_tab = np.ones((1, S)), np.ones(1)
+                blk_tab = np.zeros(1, bool)
+                hz_s = hz_e = hz_r = np.zeros(0)
+                hz_c = np.zeros((0, S), bool)
+            ar = {"slot_speed": _put(slot_speed, rep_sh),
+                  "cap": jnp.asarray(float(cap)),
+                  "i_c": jnp.asarray(float(sim.i_c)),
+                  "t_c": jnp.asarray(float(sim.t_c)),
+                  "total": jnp.asarray(float(total_steps)),
+                  "tmax": jnp.asarray(max_hours * 3600.0),
+                  "start_hour": jnp.asarray(float(start_hour)),
+                  "boundaries": _put(bounds, rep_sh),
+                  "speed_table": _put(sp_tab, rep_sh),
+                  "ps_table": _put(ps_tab, rep_sh),
+                  "blk_table": _put(blk_tab, rep_sh),
+                  "hz_start": _put(hz_s, rep_sh),
+                  "hz_end": _put(hz_e, rep_sh),
+                  "hz_rate": _put(hz_r, rep_sh),
+                  "hz_cols": _put(hz_c, rep_sh)}
+            if resilient:
+                ar["quorum"] = jnp.asarray(float(resil.degradation.quorum))
+                ar["shrink_below"] = jnp.asarray(
+                    float(resil.degradation.shrink_below))
+                ar["shrink_factor"] = jnp.asarray(
+                    float(resil.degradation.shrink_factor))
+            for name, arr in law_arrays.items():
+                ar[name] = _put(arr, rep_sh)
 
-        pad = n_pad - n
-        init_rt = np.where(np.isfinite(draws.initial),
-                           draws.initial * 3600.0, np.inf)
-        if pad:
-            init_rt = np.pad(init_rt, ((0, pad), (0, 0)),
-                             constant_values=np.inf)
-        chief0 = np.zeros((n_pad, S), bool)
-        chief0[:, 0] = True                 # FleetSim marks workers[0]
-        done0 = np.zeros(n_pad, bool)
-        done0[n:] = True                    # padding rows never run
-        st = {"t": np.zeros(n_pad), "steps": np.zeros(n_pad),
-              "last_ckpt": np.zeros(n_pad), "ckpt_time": np.zeros(n_pad),
-              "recompute": np.zeros(n_pad), "lost": np.zeros(n_pad),
-              "revocations": np.zeros(n_pad, np.int32),
-              "replacements": np.zeros(n_pad, np.int32),
-              "alive": np.ones((n_pad, S), bool), "chief": chief0,
-              "gen": np.zeros((n_pad, S), np.int32),
-              "order_key": np.tile(np.arange(S, dtype=float), (n_pad, 1)),
-              "next_key": np.full(n_pad, float(S)),
-              "revoke_t": init_rt,
-              "join_t": np.full((n_pad, S), np.inf),
-              "alive_seconds": np.zeros((n_pad, S)),
-              "done": done0, "stalled": np.zeros(n_pad, bool),
-              "orig": np.concatenate([np.arange(n, dtype=np.int32),
-                                      np.zeros(pad, np.int32)])}
-        if resilient:
-            st["stall_t"] = np.zeros(n_pad)
-            st["paused"] = np.zeros(n_pad)
-            st["restore_s"] = np.zeros(n_pad)
-        st = {key: _put(v, traj_sh) for key, v in st.items()}
+            pad = n_pad - n
+            init_rt = np.where(np.isfinite(draws.initial),
+                               draws.initial * 3600.0, np.inf)
+            if pad:
+                init_rt = np.pad(init_rt, ((0, pad), (0, 0)),
+                                 constant_values=np.inf)
+            chief0 = np.zeros((n_pad, S), bool)
+            chief0[:, 0] = True                 # FleetSim marks workers[0]
+            done0 = np.zeros(n_pad, bool)
+            done0[n:] = True                    # padding rows never run
+            st = {"t": np.zeros(n_pad), "steps": np.zeros(n_pad),
+                  "last_ckpt": np.zeros(n_pad), "ckpt_time": np.zeros(n_pad),
+                  "recompute": np.zeros(n_pad), "lost": np.zeros(n_pad),
+                  "revocations": np.zeros(n_pad, np.int32),
+                  "replacements": np.zeros(n_pad, np.int32),
+                  "alive": np.ones((n_pad, S), bool), "chief": chief0,
+                  "gen": np.zeros((n_pad, S), np.int32),
+                  "order_key": np.tile(np.arange(S, dtype=float), (n_pad, 1)),
+                  "next_key": np.full(n_pad, float(S)),
+                  "revoke_t": init_rt,
+                  "join_t": np.full((n_pad, S), np.inf),
+                  "alive_seconds": np.zeros((n_pad, S)),
+                  "done": done0, "stalled": np.zeros(n_pad, bool),
+                  "orig": np.concatenate([np.arange(n, dtype=np.int32),
+                                          np.zeros(pad, np.int32)])}
+            if resilient:
+                st["stall_t"] = np.zeros(n_pad)
+                st["paused"] = np.zeros(n_pad)
+                st["restore_s"] = np.zeros(n_pad)
+            st = {key: _put(v, traj_sh) for key, v in st.items()}
 
         if sim.replace:
             # start deep enough for every level a previous call on these
@@ -697,70 +704,71 @@ def run_jit(sim: "FleetSim", total_steps: int, n: int,
             for key in res_keys:
                 res[key][rows] = np.asarray(sub[key])
 
-        ar_g = dict(ar)
-
-        def _mount_pools():
-            for name, arr in _pools(draws, G, has_haz, resil).items():
-                ar_g[name] = (arr if traj_sh is None
-                              else jax.device_put(arr, rep_sh))
-
-        _mount_pools()
+        ar_g = dict(ar, **_pools(draws, G, has_haz, resil, rep_sh))
+        regrow = 0
         while True:
-            st = fn(st, ar_g)
-            h = jax.device_get({"done": st["done"],
-                                "stalled": st["stalled"]})
-            if np.any(h["stalled"] & valid):
+            with TraceAnnotation("fleet.loop", regrow=regrow):
+                st = fn(st, ar_g)
+                h = jax.device_get({"done": st["done"],
+                                    "stalled": st["stalled"]})
+            regrow = int(np.any(h["stalled"] & valid))
+            if regrow:
                 # deepest replacement chains outgrew the pools: double
                 # them and replay the frozen trajectories' pending rounds
                 G *= 2
-                _mount_pools()
+                ar_g.update(_pools(draws, G, has_haz, resil, rep_sh))
                 st = dict(st)
                 st["stalled"] = _put(np.zeros(len(sel), bool), traj_sh)
             keep = valid & ~np.asarray(h["done"])
             a = int(keep.sum())
             if a == 0:
-                _scatter(np.flatnonzero(valid))
+                with TraceAnnotation("fleet.compact", rows=int(valid.sum())):
+                    _scatter(np.flatnonzero(valid))
                 break
             w2 = max(COMPACT_MIN, _pow2ceil(a))
             if n_dev > 1:
                 w2 = -(-w2 // n_dev) * n_dev
             if w2 < len(sel):
-                _scatter(np.flatnonzero(valid & ~keep))
-                idx = np.zeros(w2, np.int32)
-                idx[:a] = np.flatnonzero(keep)
-                idx_d = _put(idx, traj_sh)
-                padmask = np.zeros(w2, bool)
-                padmask[a:] = True
-                st = {key: _put(jnp.take(v, idx_d, axis=0), traj_sh)
-                      for key, v in st.items()}
-                st["done"] = jnp.logical_or(st["done"],
-                                            _put(padmask, traj_sh))
+                leaving = np.flatnonzero(valid & ~keep)
+                with TraceAnnotation("fleet.compact", rows=leaving.size):
+                    _scatter(leaving)
+                    idx = np.zeros(w2, np.int32)
+                    idx[:a] = np.flatnonzero(keep)
+                    idx_d = _put(idx, traj_sh)
+                    padmask = np.zeros(w2, bool)
+                    padmask[a:] = True
+                    st = {key: _put(jnp.take(v, idx_d, axis=0), traj_sh)
+                          for key, v in st.items()}
+                    st["done"] = jnp.logical_or(st["done"],
+                                                _put(padmask, traj_sh))
                 sel = sel[idx]
                 valid = ~padmask
 
-    price = np.array([sim.price_of.get(g, 0.0) for _, g, _, _ in roster])
-    cost = (res["alive_seconds"] / 3600.0) @ price
-    regions = {region for _, _, region, _ in roster}
-    region = regions.pop() if len(regions) == 1 else ""
-    if raw:
-        return {"total_time_s": res["t"],
-                "steps_done": (res["steps"] + 1e-6).astype(np.int64),
-                "revocations": res["revocations"],
-                "replacements": res["replacements"],
-                "checkpoint_time_s": res["ckpt_time"],
-                "recompute_time_s": res["recompute"],
-                "lost_steps": res["lost"], "monetary_cost": cost,
-                "paused_s": res["paused"],
-                "restore_delay_s": res["restore_s"]}
-    return [SimResult(
-        total_time_s=float(res["t"][j]),
-        steps_done=int(res["steps"][j] + 1e-6),
-        revocations=int(res["revocations"][j]),
-        replacements=int(res["replacements"][j]),
-        checkpoint_time_s=float(res["ckpt_time"][j]),
-        recompute_time_s=float(res["recompute"][j]),
-        lost_steps=float(res["lost"][j]),
-        events=[], monetary_cost=float(cost[j]),
-        provider=sim.provider.name, region=region,
-        paused_s=float(res["paused"][j]),
-        restore_delay_s=float(res["restore_s"][j])) for j in range(n)]
+    with TraceAnnotation("fleet.results"):
+        price = np.array([sim.price_of.get(g, 0.0)
+                          for _, g, _, _ in roster])
+        cost = (res["alive_seconds"] / 3600.0) @ price
+        regions = {region for _, _, region, _ in roster}
+        region = regions.pop() if len(regions) == 1 else ""
+        if raw:
+            return {"total_time_s": res["t"],
+                    "steps_done": (res["steps"] + 1e-6).astype(np.int64),
+                    "revocations": res["revocations"],
+                    "replacements": res["replacements"],
+                    "checkpoint_time_s": res["ckpt_time"],
+                    "recompute_time_s": res["recompute"],
+                    "lost_steps": res["lost"], "monetary_cost": cost,
+                    "paused_s": res["paused"],
+                    "restore_delay_s": res["restore_s"]}
+        return [SimResult(
+            total_time_s=float(res["t"][j]),
+            steps_done=int(res["steps"][j] + 1e-6),
+            revocations=int(res["revocations"][j]),
+            replacements=int(res["replacements"][j]),
+            checkpoint_time_s=float(res["ckpt_time"][j]),
+            recompute_time_s=float(res["recompute"][j]),
+            lost_steps=float(res["lost"][j]),
+            events=[], monetary_cost=float(cost[j]),
+            provider=sim.provider.name, region=region,
+            paused_s=float(res["paused"][j]),
+            restore_delay_s=float(res["restore_s"][j])) for j in range(n)]
