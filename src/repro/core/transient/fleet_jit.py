@@ -499,7 +499,7 @@ def _pools(draws: "FleetDraws", G: int, has_chaos: bool, res=None,
     level paging freezes any revoke whose occupant reached G before it
     mutates state, so the index never pages off the pool). A build
     runs in a `fleet.pools` span whose `draws` stat counts the chaos
-    join uniforms drawn, one keyed generator each."""
+    join uniforms drawn, every level of the build in one array call."""
     key = (G, bool(has_chaos), res, sharding)
     cache = draws.__dict__.setdefault("_jit_pool_cache", {})
     if key in cache:
@@ -520,11 +520,8 @@ def _pools(draws: "FleetDraws", G: int, has_chaos: bool, res=None,
             for g in range(G):
                 stalls[g] = draws.restore_stall_level(res, g).T
             out["stalls"] = stalls.reshape(G * S, n)
-        ju = np.empty((G, S, n, F))
-        if F:
-            for g in range(1, G + 1):
-                ju[g - 1] = np.swapaxes(
-                    draws.chaos.join_uniform_matrix(n, g), 0, 1)
+        ju = (np.swapaxes(draws.chaos.join_uniform_matrix(
+            n, range(1, G + 1)), 1, 2) if F else np.empty((G, S, n, 0)))
         out["join_U"] = ju.reshape(G * S, n, F)
         out = {name: _put(arr, sharding) for name, arr in out.items()}
     cache.clear()            # keep at most one (the deepest) G resident

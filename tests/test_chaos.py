@@ -3,10 +3,12 @@ keyed-hazard determinism contract, two-engine parity under every
 registered scenario, the ground-truth evaluator on hand-built histories,
 and the live detect -> attribute -> mitigate runs behind
 `python -m repro chaos`."""
+import contextlib
 import json
 
 import numpy as np
 import pytest
+from jax import enable_x64
 
 from repro.api import Session
 from repro.chaos import (CheckpointOutage, FaultTimeline, LiveFault,
@@ -14,8 +16,11 @@ from repro.chaos import (CheckpointOutage, FaultTimeline, LiveFault,
                          Scenario, StragglerFault, get_scenario,
                          list_scenarios, register_scenario, run_scenario,
                          score_history)
+from repro.chaos.injectors import keyed_uniforms
 from repro.chaos.runner import _run_sim
+from repro.core.transient import fleet_jit
 from repro.core.transient.fleet import FleetSim, SimWorker
+from repro.core.transient.fleet_batched import FleetDraws
 
 
 @pytest.fixture(scope="module")
@@ -129,6 +134,81 @@ def test_join_transform_independent_of_batch_grouping():
                            gens[i:i + 1], hours[i:i + 1])[0]
         for i in range(4)])
     np.testing.assert_array_equal(batch, single)
+
+
+@pytest.mark.parametrize("n_words", [1, 3, 4, 6, 9])
+def test_keyed_uniforms_equal_numpy_seedsequence_draws(n_words):
+    """The array form of the keyed draws is numpy's scalar stream, bit for
+    bit: key lengths below, at and above the SeedSequence pool size, with
+    zeros and words near 2**32."""
+    rng = np.random.default_rng(n_words)
+    keys = rng.integers(0, 2 ** 32, (200, n_words), dtype=np.int64)
+    keys[::3, 0] = 0
+    keys[1::5, -1] = 2 ** 32 - 1
+    got = keyed_uniforms(*keys.T)
+    want = [np.random.default_rng(np.random.SeedSequence(
+        tuple(int(w) for w in k))).random() for k in keys]
+    np.testing.assert_array_equal(got, want)
+    with pytest.raises(ValueError, match="2\\*\\*32"):
+        keyed_uniforms(2 ** 32, 1)
+
+
+def test_join_uniform_matrix_equals_transform_joins_draws(monkeypatch):
+    """Every level of the jit engine's join-uniform matrix holds exactly
+    the scalar draw `transform_joins` makes for the same (fault, traj,
+    slot, gen) key — two hazard faults in two regions, behind a
+    non-hazard fault so the fault index is not the hazard index."""
+    roster = [(i, "v100", r, 15.0) for i, r in enumerate(
+        ("us-central1", "europe-west1", "us-central1", "europe-west1"))]
+    tl = FaultTimeline((PSCrash(0.0, 1.0, 0.5),
+                        PreemptionWave(0.5, 1.0, 6.0, region="us-central1"),
+                        PriceSpike(1.0, 2.0, 3.0, region="europe-west1")),
+                       roster, seed=2 ** 32 + 17)
+    n, G = 5, 8
+    got = tl.join_uniform_matrix(n, range(1, G + 1))
+    assert got.shape == (G, n, 4, 2)
+    seen = []
+    apply = FaultTimeline._apply_hazard
+
+    def spy(lt, U, f, h0):
+        seen.append(np.array(U))
+        return apply(lt, U, f, h0)
+
+    monkeypatch.setattr(FaultTimeline, "_apply_hazard", staticmethod(spy))
+    gens, trajs, slots = (a.ravel() for a in np.meshgrid(
+        np.arange(1, G + 1), np.arange(n), np.arange(4), indexing="ij"))
+    tl.transform_joins(np.ones(gens.size), trajs, slots, gens,
+                       np.zeros(gens.size))
+    assert len(seen) == 2
+    for k, U in enumerate(seen):
+        np.testing.assert_array_equal(got[..., k], U.reshape(G, n, 4))
+
+
+def test_jit_pools_levels_independent_of_depth(monkeypatch):
+    """A deeper pool build holds the shallower one's levels unchanged, so
+    the level-paging schedule cannot change results; the `fleet.pools`
+    span's `draws` stat counts G x S x n x F join uniforms."""
+    stats = []
+
+    @contextlib.contextmanager
+    def record(name, **kw):
+        stats.append((name, kw))
+        yield
+
+    monkeypatch.setattr(fleet_jit, "TraceAnnotation", record)
+    sim = _mk_sim(seed=5)
+    sim.chaos = _timeline((PreemptionWave(0.25, 1.0, 6.0),
+                           PriceSpike(0.5, 2.0, 2.0)), sim=sim, seed=9)
+    n, S, F = 5, 4, 2
+    with enable_x64(True):
+        shallow = fleet_jit._pools(FleetDraws(sim, n, 0.0), 4, True)
+        deep = fleet_jit._pools(FleetDraws(sim, n, 0.0), 8, True)
+        for name, arr in shallow.items():
+            np.testing.assert_array_equal(np.asarray(deep[name])[:4 * S],
+                                          np.asarray(arr))
+        assert deep["join_U"].shape == (8 * S, n, F)
+    assert stats == [("fleet.pools", {"levels": G, "draws": G * S * n * F})
+                     for G in (4, 8)]
 
 
 # ------------------------------------------------- engine parity
