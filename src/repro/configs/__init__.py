@@ -6,6 +6,7 @@ from repro.configs.base import (  # noqa: F401
     MoEConfig,
     ModelConfig,
     PREFILL_32K,
+    RopeScaling,
     RunConfig,
     SHAPES,
     ShapeConfig,

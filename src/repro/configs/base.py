@@ -7,19 +7,30 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Mapping, Optional, Tuple
 
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 0            # routed experts
+    """Routed experts behind a softmax router, dropless.
+
+    The router scores all `n_experts`; a layer holds `n_held` of them
+    (0: all), from `first_held` on, and computes only their part of the
+    result, as one chip of an expert-parallel layer does.
+    """
+    n_experts: int = 0            # routed experts the router scores
     top_k: int = 0
     n_shared_experts: int = 0     # always-on experts (DeepSeek style)
     expert_d_ff: int = 0          # per-expert hidden size
-    capacity_factor: float = 1.25
-    group_size: int = 4096        # tokens per routing group (local sort dispatch)
-    router_jitter: float = 0.0
+    n_held: int = 0               # experts held here; 0 => all
+    first_held: int = 0           # index of the first held expert
+    norm_topk_prob: bool = True   # renormalise the top-k weights to sum 1
+    seq_aux: bool = False         # balance loss per sequence (DeepSeek-V2)
     aux_loss_coef: float = 0.01
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -30,6 +41,18 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling, under the published config's key names."""
+    type: str = "yarn"
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -58,6 +81,7 @@ class ModelConfig:
     # attention flavor
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
     partial_rotary: float = 1.0     # fraction of head_dim that rotates
     mrope_sections: Tuple[int, ...] = ()  # M-RoPE (qwen2-vl): dims per (t,h,w)
     causal: bool = True             # False => encoder (hubert)
@@ -83,6 +107,14 @@ class ModelConfig:
     # dry-run probes: python-loop layers instead of lax.scan so XLA
     # cost_analysis sees every layer (scan bodies are costed only once)
     unroll_layers: bool = False
+
+    def __post_init__(self):
+        # sub-configs given as mappings (a JSON file's nested groups)
+        for name, cls in (("moe", MoEConfig), ("mla", MLAConfig),
+                          ("rope_scaling", RopeScaling)):
+            v = getattr(self, name)
+            if isinstance(v, Mapping):
+                object.__setattr__(self, name, cls(**v))
 
     # ---- derived quantities -------------------------------------------------
     @property
@@ -112,7 +144,7 @@ class ModelConfig:
                 total += self.first_k_dense * self._mlp_params(self.dense_d_ff or self.d_ff)
                 per_expert = self._mlp_params(self.moe.expert_d_ff)
                 total += moe_layers * (
-                    (self.moe.n_experts + self.moe.n_shared_experts) * per_expert
+                    (self.moe.held + self.moe.n_shared_experts) * per_expert
                     + self.d_model * self.moe.n_experts  # router
                 )
             else:
@@ -168,14 +200,18 @@ class ModelConfig:
         return flops
 
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: only top-k + shared experts)."""
+        """Parameters touched per token (MoE: only top-k + shared experts;
+        a layer holding a share of the experts touches that share of the
+        top-k on average)."""
         if not self.moe:
             return self.param_count()
         total = self.param_count()
         moe_layers = self.n_layers - self.first_k_dense
         per_expert = self._mlp_params(self.moe.expert_d_ff)
-        inactive = moe_layers * (self.moe.n_experts - self.moe.top_k) * per_expert
-        return total - inactive
+        mo = self.moe
+        routed = mo.top_k * mo.held / mo.n_experts
+        inactive = moe_layers * (mo.held - routed) * per_expert
+        return int(total - inactive)
 
 
 @dataclass(frozen=True)

@@ -13,11 +13,9 @@ CONFIG = ModelConfig(
     d_ff=512,                 # == expert_d_ff; all FFNs are MoE
     vocab_size=49155,
     tie_embeddings=True,
-    moe=MoEConfig(n_experts=40, top_k=8, expert_d_ff=512,
-                  capacity_factor=1.25, group_size=4096),
+    moe=MoEConfig(n_experts=40, top_k=8, expert_d_ff=512),
 )
 
 SMOKE = CONFIG.with_(n_layers=2, d_model=128, n_heads=4, n_kv_heads=2,
                      head_dim=32, d_ff=64, vocab_size=512,
-                     moe=MoEConfig(n_experts=8, top_k=2, expert_d_ff=64,
-                                   capacity_factor=1.5, group_size=64))
+                     moe=MoEConfig(n_experts=8, top_k=2, expert_d_ff=64))

@@ -44,6 +44,7 @@ from repro.dist import sharding as sh
 from repro.dist.elastic import ElasticMembership, Member
 from repro.launch import steps as st
 from repro.models import api
+from repro.models.layers import MOE_COUNTERS
 
 
 @dataclasses.dataclass
@@ -335,9 +336,17 @@ class TransientTrainer:
                 with TraceAnnotation("train.dispatch"):
                     state, metrics = self._jit_step(state, batch)
                 steps_run += 1
-                with TraceAnnotation("train.sync"):
-                    loss = float(metrics["loss"])
-                    payload = {"step": step, "loss": loss}
+                with TraceAnnotation("train.sync") as sync:
+                    # the loss and the expert layers' counters, in one
+                    # transfer; the counters go on this span as stats
+                    host = jax.device_get(
+                        {k: metrics[k] for k in ("loss",) + MOE_COUNTERS
+                         if k in metrics})
+                    loss = float(host.pop("loss"))
+                    counters = {k: int(v) for k, v in host.items()}
+                    if counters:
+                        sync.set_metadata(**counters)
+                    payload = {"step": step, "loss": loss, **counters}
                     if "payload_bytes" in metrics:
                         # §VI-B telemetry: the actual compressed wire size
                         # of this step's gradient push, not a config echo

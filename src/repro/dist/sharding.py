@@ -39,7 +39,6 @@ Rules = Dict[str, Rule]
 # parallelism over ("pod", "data").
 MEGATRON_RULES: Rules = {
     "batch": ("pod", "data"),
-    "moe_groups": ("pod", "data"),
     "heads": "model",
     "kv_heads": "model",
     "ff": "model",
@@ -58,7 +57,6 @@ DECODE_RULES: Rules = dict(MEGATRON_RULES, batch=("pod", "data", "model"))
 # Expert parallelism: experts across "model", everything else data-parallel.
 EP_RULES: Rules = {
     "batch": ("pod", "data"),
-    "moe_groups": ("pod", "data"),
     "experts": "model",
     "vocab": "model",
 }
@@ -66,13 +64,11 @@ EP_RULES: Rules = {
 # Pure data parallelism: flatten every mesh axis into the batch.
 DP_RULES: Rules = {
     "batch": ("pod", "data", "model"),
-    "moe_groups": ("pod", "data", "model"),
 }
 
 # DP + EP hybrid (MoE without tensor parallelism).
 DPEP_RULES: Rules = {
     "batch": ("pod", "data"),
-    "moe_groups": ("pod", "data"),
     "experts": "model",
 }
 
@@ -81,7 +77,6 @@ DPEP_RULES: Rules = {
 # rule replicates "embed" wherever "batch" already took "data").
 FSDP_RULES: Rules = {
     "batch": ("pod", "data"),
-    "moe_groups": ("pod", "data"),
     "embed": "data",
     "vocab": "model",
 }
@@ -205,3 +200,24 @@ def constrain(x, *names: Optional[str]):
     mesh, rules = ctx
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, spec(names, rules, mesh, x.shape)))
+
+
+def per_batch_shard(fn, split, whole):
+    """`fn(*split, *whole)` on each shard of the mesh axes that "batch"
+    resolves to, with no exchange between the shards: each array of
+    `split` is cut along its leading axis, each of `whole` is handed to
+    every shard as it is (its other mesh axes stay the compiler's), and
+    every output is cut along its leading axis. Outside a sharding
+    context, or where "batch" resolves to no mesh axis, it is `fn`."""
+    ctx = current_sharding()
+    axes = None
+    if ctx is not None:
+        mesh, rules = ctx
+        axes = spec(("batch",), rules, mesh, split[0].shape[:1])[0]
+    if axes is None:
+        return fn(*split, *whole)
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(P(axes),) * len(split) + (P(),) * len(whole),
+        out_specs=P(axes), axis_names=frozenset(axes),
+        check_vma=False)(*split, *whole)

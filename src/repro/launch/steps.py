@@ -117,7 +117,8 @@ def make_train_step(cfg: ModelConfig, run: RunConfig, mesh=None,
     folded in, the sum is quantize-decompressed, and the quantization
     error becomes the next step's residual. Metrics then include
     ``payload_bytes`` — the actual compressed push size the trainer
-    reports on the event bus.
+    reports on the event bus. A model with expert layers adds their
+    counters (``layers.MOE_COUNTERS``) to the metrics.
     """
     lr = cosine_warmup(run.lr, run.warmup_steps, run.total_steps)
     opt = make_optimizer(run.optimizer, lr, run.weight_decay,
@@ -126,9 +127,7 @@ def make_train_step(cfg: ModelConfig, run: RunConfig, mesh=None,
           if run.grad_compression != "none" else None)
 
     def train_step(state: TrainState, batch):
-        def loss_of(p):
-            return api.loss_fn(p, cfg, batch)
-
+        counters = {}
         if run.microbatch and run.microbatch > 1:
             n = run.microbatch
             split = jax.tree.map(
@@ -145,12 +144,13 @@ def make_train_step(cfg: ModelConfig, run: RunConfig, mesh=None,
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
             (loss, grads), _ = jax.lax.scan(micro, (0.0, zeros), split)
         else:
-            loss, grads = jax.value_and_grad(loss_of)(state.params)
+            (loss, counters), grads = jax.value_and_grad(
+                api.loss_and_counters, has_aux=True)(state.params, cfg, batch)
 
         grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
         residual = state.residual
         metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm,
-                   "step": state.step}
+                   "step": state.step, **counters}
         if ef is not None:
             grads, residual = ef.roundtrip(grads, residual)
             metrics["payload_bytes"] = jnp.asarray(

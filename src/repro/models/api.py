@@ -70,16 +70,25 @@ def cross_entropy(logits, labels):
     return jnp.mean(logz - gold)
 
 
-def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray]):
+def loss_and_counters(params, cfg: ModelConfig,
+                      batch: Dict[str, jnp.ndarray]):
+    """(loss, counters): the training loss, and the forward's integer
+    counters (the expert layers' routing, `layers.MOE_COUNTERS`; {} for a
+    model without expert layers)."""
     mod = _module(cfg)
+    counters = {}
     if cfg.family == "audio":
         logits, aux = mod.forward(params, cfg, batch["features"])
-    elif cfg.family == "vlm":
-        logits, aux = mod.forward(params, cfg, batch["tokens"],
-                                  positions=batch.get("positions"))
+    elif mod is transformer:
+        logits, aux, counters = transformer.forward_with_counters(
+            params, cfg, batch["tokens"], positions=batch.get("positions"))
     else:
         logits, aux = mod.forward(params, cfg, batch["tokens"])
-    return cross_entropy(logits, batch["labels"]) + aux
+    return cross_entropy(logits, batch["labels"]) + aux, counters
+
+
+def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray]):
+    return loss_and_counters(params, cfg, batch)[0]
 
 
 def forward(params, cfg: ModelConfig, *args, **kw):

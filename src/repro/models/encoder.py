@@ -55,7 +55,7 @@ def forward(params, cfg: ModelConfig, features, positions=None,
         positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
 
     def body(x, lp):
-        x, aux, _ = _layer_apply(lp, cfg, x, positions, is_dense_ffn=False)
+        x, aux, _, _ = _layer_apply(lp, cfg, x, positions, is_dense_ffn=False)
         return x, aux
 
     x, _ = scan_layers(body, x, params["layers"], cfg)

@@ -1,5 +1,5 @@
-"""Shared neural-net layers: norms, RoPE/M-RoPE, GQA + MLA attention (train,
-prefill and single-token decode paths), SwiGLU MLP, grouped-capacity MoE.
+"""Shared neural-net layers: norms, RoPE/M-RoPE/YaRN, GQA + MLA attention
+(train, prefill and single-token decode paths), SwiGLU MLP, dropless MoE.
 
 Param convention: every parameter is created as ``Param(value, axes)`` where
 ``axes`` is a tuple of *logical* axis names (see dist/sharding.py). The model
@@ -9,6 +9,7 @@ NamedShardings without a parallel spec tree drifting out of sync.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -17,7 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.dist.sharding import constrain
+from repro.dist.sharding import constrain, per_batch_shard
 
 
 # ---------------------------------------------------------------------------
@@ -91,20 +92,55 @@ def _quant_int8(x):
 # ---------------------------------------------------------------------------
 # Rotary position embeddings (standard / partial / M-RoPE)
 # ---------------------------------------------------------------------------
-def rope_freqs(rot_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, 0.1 m ln s + 1 (1 for s <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_ramp(rot_dim: int, theta: float, sc) -> jnp.ndarray:
+    """Share (0..1) of each frequency that is interpolated: 0 below the
+    correction dim of `beta_fast` rotations over the original context,
+    1 above that of `beta_slow`, linear between (DeepSeek-V2's YaRN)."""
+    def dim_of(rotations):
+        return (rot_dim * math.log(sc.original_max_position_embeddings
+                                   / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(sc.beta_fast)), 0)
+    high = min(math.ceil(dim_of(sc.beta_slow)), rot_dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(rot_dim // 2, dtype=jnp.float32)
+    return jnp.clip((i - low) / (high - low), 0.0, 1.0)
+
+
+def rope_freqs(rot_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    inv = 1.0 / (theta ** (jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
+    if scaling is None:
+        return inv
+    ramp = _yarn_ramp(rot_dim, theta, scaling)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
+def softmax_scale(qk_dim: int, scaling=None) -> float:
+    """1/sqrt(qk_dim), times YaRN's mscale(factor, mscale_all_dim)^2."""
+    scale = 1.0 / math.sqrt(qk_dim)
+    if scaling is not None and scaling.mscale_all_dim:
+        scale *= yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+    return scale
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
                rot_frac: float = 1.0,
-               mrope_sections: Tuple[int, ...] = ()) -> jnp.ndarray:
-    """x: (B,S,H,hd). positions: (B,S) or (3,B,S) for M-RoPE."""
+               mrope_sections: Tuple[int, ...] = (),
+               scaling=None) -> jnp.ndarray:
+    """x: (B,S,H,hd). positions: (B,S) or (3,B,S) for M-RoPE. `scaling`:
+    a `RopeScaling` (YaRN) or None."""
     hd = x.shape[-1]
     rot_dim = int(hd * rot_frac)
     if rot_dim == 0:
         return x
     rot_dim -= rot_dim % 2
-    inv = rope_freqs(rot_dim, theta)  # (rot_dim/2,)
+    inv = rope_freqs(rot_dim, theta, scaling)  # (rot_dim/2,)
     if mrope_sections:
         assert positions.ndim == 3, "M-RoPE needs (3,B,S) positions"
         secs = mrope_sections
@@ -120,6 +156,11 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
         angles = positions[..., None].astype(jnp.float32) * inv  # (B,S,rot_dim/2)
     cos = jnp.cos(angles)[:, :, None, :]  # (B,S,1,rot_dim/2)
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     xr, xp = x[..., :rot_dim], x[..., rot_dim:]
     x1, x2 = xr[..., : rot_dim // 2], xr[..., rot_dim // 2:]
     out1 = x1 * cos - x2 * sin
@@ -147,18 +188,22 @@ def init_attention(key, cfg: ModelConfig) -> Dict[str, Param]:
     return p
 
 
-def _chunked_attn(q, k, v, causal: bool, q_offset, chunk: int = 1024):
+def _chunked_attn(q, k, v, causal: bool, q_offset, scale=None,
+                  chunk: int = 1024):
     """q:(B,Sq,H,hd) k,v:(B,Sk,KV,hd) -> (B,Sq,H,hd). GQA by head broadcast.
+    `scale`: the softmax scale (default 1/sqrt(hd)).
 
-    Scans over query chunks with a full online-softmax against k/v; O(Sq/chunk)
-    steps, peak score memory B*chunk*Sk per head group.
+    Scans over query chunks with a full softmax against k/v; O(Sq/chunk)
+    steps. Each chunk is recomputed in the backward pass, so peak score
+    memory is B*chunk*Sk per head group, forward and backward.
     """
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     vd = v.shape[-1]
     G = H // KV
     qg = q.reshape(B, Sq, KV, G, hd)
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     if Sq <= chunk:
         return _attn_block(qg, k, v, causal, q_offset, 0, scale
                            ).reshape(B, Sq, H, vd)
@@ -166,6 +211,7 @@ def _chunked_attn(q, k, v, causal: bool, q_offset, chunk: int = 1024):
     assert Sq % chunk == 0, (Sq, chunk)
     qc = qg.reshape(B, n, chunk, KV, G, hd).transpose(1, 0, 2, 3, 4, 5)
 
+    @functools.partial(jax.checkpoint, prevent_cse=False)
     def body(i, qi):
         out = _attn_block(qi, k, v, causal, q_offset, i * chunk, scale)
         return i + 1, out
@@ -302,6 +348,7 @@ def init_mla(key, cfg: ModelConfig) -> Dict[str, Param]:
     }
 
 
+@jax.named_scope("mla.attention")
 def mla_attention(params, cfg: ModelConfig, x, positions,
                   cache: Optional[Dict[str, jnp.ndarray]] = None,
                   cache_index=None):
@@ -309,15 +356,17 @@ def mla_attention(params, cfg: ModelConfig, x, positions,
     B, S, _ = x.shape
     H = cfg.n_heads
     nope, rope_d, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
-    scale = 1.0 / math.sqrt(nope + rope_d)
+    sc = cfg.rope_scaling
+    scale = softmax_scale(nope + rope_d, sc)
 
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, scaling=sc)
     c_kv = x @ params["wdkv"].astype(x.dtype)                       # (B,S,r)
     c_kv = rmsnorm({"scale": params["kv_norm"]}, c_kv, cfg.norm_eps)
     k_rope = (x @ params["wkrope"].astype(x.dtype))[:, :, None, :]  # (B,S,1,rd)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)[:, :, 0]  # (B,S,rd)
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta,
+                        scaling=sc)[:, :, 0]                            # (B,S,rd)
 
     if cache is not None:
         # absorbed decode: q_lat = q_nope @ W_uk  -> score against c_kv cache
@@ -348,7 +397,7 @@ def mla_attention(params, cfg: ModelConfig, x, positions,
         q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
         q_full = constrain(q_full, "batch", "seq", "heads", None)
         k_full = constrain(k_full, "batch", "seq", "heads", None)
-        out = _chunked_attn(q_full, k_full, v, cfg.causal, 0)
+        out = _chunked_attn(q_full, k_full, v, cfg.causal, 0, scale)
     out = constrain(out, "batch", "seq", "heads", None)
     y = jnp.einsum("bshv,hvd->bsd", out, params["wo"].astype(x.dtype))
     return constrain(y, "batch", "seq", "embed"), new_cache
@@ -380,95 +429,119 @@ def mlp(params, x):
 
 
 # ---------------------------------------------------------------------------
-# MoE: grouped-capacity sort dispatch (static shapes, local per-group sort —
-# no global collectives in the dispatch itself; expert FFNs are TP-sharded).
+# MoE: dropless routing over the experts this layer holds. The router scores
+# all experts; the assignments that land on a held expert are sorted by
+# expert and run through a grouped matmul (`lax.ragged_dot`), with no
+# capacity and nothing dropped. The sort and the grouped matmul run on each
+# shard of a data-sharded batch by itself, over its own tokens. A layer
+# holding a share of the experts (one chip of an expert-parallel layer)
+# gives that share's part of the result.
 # ---------------------------------------------------------------------------
+MOE_COUNTERS = ("moe_routed_held", "moe_max_load")
+
+
 def init_moe(key, cfg: ModelConfig) -> Dict[str, Param]:
     mo = cfg.moe
-    d, E, f = cfg.d_model, mo.n_experts, mo.expert_d_ff
+    d, E, n, f = cfg.d_model, mo.n_experts, mo.held, mo.expert_d_ff
     ks = jax.random.split(key, 5)
     p = {
         "router": _dense_init(ks[0], (d, E), ("embed", "experts"),
                               scale=0.02),
-        "wi": _dense_init(ks[1], (E, d, f), ("experts", "embed", "ff")),
-        "wg": _dense_init(ks[2], (E, d, f), ("experts", "embed", "ff")),
-        "wo": _dense_init(ks[3], (E, f, d), ("experts", "ff", "embed")),
+        "wi": _dense_init(ks[1], (n, d, f), ("experts", "embed", "ff")),
+        "wg": _dense_init(ks[2], (n, d, f), ("experts", "embed", "ff")),
+        "wo": _dense_init(ks[3], (n, f, d), ("experts", "ff", "embed")),
     }
     if mo.n_shared_experts:
         p["shared"] = init_mlp(ks[4], d, mo.n_shared_experts * f)
     return p
 
 
-def _group_dispatch(xg, eid, w, n_experts: int, cap: int):
-    """xg:(g,d) eid,w:(g,k). Returns (buf (E*cap,d), combine metadata)."""
-    g, k = eid.shape
-    flat_e = eid.reshape(-1)
-    flat_w = w.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    counts = jnp.zeros((n_experts,), jnp.int32).at[sorted_e].add(1)
-    starts = jnp.cumsum(counts) - counts
-    pos = jnp.arange(g * k, dtype=jnp.int32) - starts[sorted_e]
-    keep = pos < cap
-    dest = jnp.where(keep, sorted_e * cap + pos, n_experts * cap)  # drop row
-    tok = order // k
-    buf = jnp.zeros((n_experts * cap + 1, xg.shape[-1]), xg.dtype)
-    buf = buf.at[dest].set(xg[tok])
-    meta = (dest, tok, flat_w[order], keep)
-    return buf[:-1], meta
-
-
-def _group_combine(out_buf, meta, g: int, k: int, d: int):
-    dest, tok, w_sorted, keep = meta
-    padded = jnp.concatenate([out_buf, jnp.zeros((1, d), out_buf.dtype)])
-    pair_out = padded[jnp.where(keep, dest, out_buf.shape[0])]
-    y = jnp.zeros((g, d), out_buf.dtype)
-    y = y.at[tok].add(pair_out * w_sorted[:, None].astype(out_buf.dtype))
-    return y
-
-
-def moe(params, cfg: ModelConfig, x, router_key=None):
-    """x: (B,S,d) -> (y, aux_loss)."""
-    mo = cfg.moe
-    B, S, d = x.shape
+def _balance_loss(probs, top_e, mo, batch: int):
+    """E * sum_e f_e * P_e, times the coefficient: f_e is expert e's share
+    of the top-k assignments over E, P_e its mean router probability. Over
+    each sequence and then averaged with `seq_aux` (DeepSeek-V2), else over
+    all tokens at once."""
     E, k = mo.n_experts, mo.top_k
-    T = B * S
-    g = min(mo.group_size, T)
-    assert T % g == 0, (T, g)
-    G = T // g
-    cap = int(math.ceil(g * k / E * mo.capacity_factor))
-    cap = max(8, min(cap + (-cap) % 8, g))
+    groups = batch if mo.seq_aux else 1
+    p = probs.reshape(groups, -1, E)
+    e = top_e.reshape(groups, -1, k)
+    ce = jnp.sum(jax.nn.one_hot(e, E, dtype=jnp.float32), axis=(1, 2)) / (
+        e.shape[1] * k)
+    return E * jnp.mean(jnp.sum(jnp.mean(p, axis=1) * ce, axis=-1)) * \
+        mo.aux_loss_coef
 
-    xf = x.reshape(G, g, d)
-    xf = constrain(xf, "moe_groups", None, "embed")
-    logits = jnp.einsum("Ggd,de->Gge", xf, params["router"].astype(x.dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_w, top_e = lax.top_k(probs, k)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
 
-    # load-balancing aux loss (Switch): E * sum_e f_e * p_e
-    me = jnp.mean(probs, axis=(0, 1))
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=2),
-        axis=(0, 1)) / k
-    aux = E * jnp.sum(me * ce) * mo.aux_loss_coef
+@jax.checkpoint
+def _held_experts(xf, tok, w, sizes, wg, wi, wo):
+    """xf: (T,d) tokens; tok, w: (M,) each assignment's token and gate,
+    sorted by held expert; sizes: (n,) assignments per held expert, the
+    rows past their sum unused. -> (T,d) float32: each token's gated sum
+    of its held experts' outputs. The buffer holds every assignment that
+    can land here, so it is recomputed in the backward pass rather than
+    kept: only the tokens and the routing are saved."""
+    # the grouped matmuls leave the rows past the groups unwritten (on the
+    # TPU they hold whatever the buffer held): select them away on the way
+    # in and out, so that neither pass reads them
+    used = (jnp.arange(tok.shape[0]) < jnp.sum(sizes))[:, None]
+    with jax.named_scope("moe.dispatch"):
+        xs = jnp.where(used, xf[tok], 0)
+    with jax.named_scope("moe.experts"):
+        h = jax.nn.silu(lax.ragged_dot(xs, wg.astype(xs.dtype), sizes)) * \
+            lax.ragged_dot(xs, wi.astype(xs.dtype), sizes)
+        h = h * w[:, None].astype(h.dtype)
+        out = lax.ragged_dot(h, wo.astype(xs.dtype), sizes)
+    with jax.named_scope("moe.combine"):
+        return jnp.zeros(xf.shape, jnp.float32).at[tok].add(
+            jnp.where(used, out.astype(jnp.float32), 0.0))
 
-    bufs, metas = jax.vmap(
-        lambda xi, ei, wi: _group_dispatch(xi, ei, wi, E, cap))(xf, top_e, top_w)
-    bufs = bufs.reshape(G, E, cap, d)
-    # "experts" resolves to None (TP-inside-experts, megatron rules) or to
-    # "model" (expert parallelism, EP rules) — the all-to-all appears here.
-    bufs = constrain(bufs, "moe_groups", "experts", "expert_cap", "embed")
-    h = jax.nn.silu(jnp.einsum("Gecd,edf->Gecf", bufs,
-                               params["wg"].astype(x.dtype))) * \
-        jnp.einsum("Gecd,edf->Gecf", bufs, params["wi"].astype(x.dtype))
-    h = constrain(h, "moe_groups", "experts", "expert_cap", "ff")
-    out_buf = jnp.einsum("Gecf,efd->Gecd", h, params["wo"].astype(x.dtype))
-    out_buf = constrain(out_buf, "moe_groups", "experts", "expert_cap", "embed")
 
-    y = jax.vmap(lambda ob, m: _group_combine(ob.reshape(E * cap, d), m, g, k, d)
-                 )(out_buf, metas)
-    y = y.reshape(B, S, d)
+def _routed(x, top_e, top_w, wg, wi, wo, *, first: int):
+    """One shard's tokens x: (B,S,d), their top-k experts and gates ->
+    ((B,S,d) float32 held experts' part, (1,n) assignments per held
+    expert)."""
+    B, S, d = x.shape
+    k, n = top_e.shape[-1], wg.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        local = top_e.reshape(-1) - first
+        held = (local >= 0) & (local < n)
+        # held assignments sorted by expert; the others sort last, past
+        # every group, where the grouped matmuls neither read nor write
+        slot = jnp.where(held, local, n)
+        order = jnp.argsort(slot, stable=True)[:B * S * min(k, n)]
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[slot].add(1)[:n]
+        tok = order // k
+        # zero past the held rows, the backward pass's too
+        w = jnp.where(held[order], top_w.reshape(-1)[order], 0.0)
+    y = _held_experts(x.reshape(B * S, d), tok, w, sizes, wg, wi, wo)
+    return y.reshape(B, S, d), sizes[None]
+
+
+def moe(params, cfg: ModelConfig, x):
+    """x: (B,S,d) -> (y, aux_loss, counters). `y` is the held experts' part
+    of the routed result plus the shared experts; `counters` gives the
+    assignments computed here and the largest held expert's load."""
+    mo = cfg.moe
+    B = x.shape[0]
+
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                            params["router"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        # on each shard: the partitioner gathers the batch for a top_k
+        top_w, top_e = per_batch_shard(
+            functools.partial(lax.top_k, k=mo.top_k), (probs,), ())
+        if mo.norm_topk_prob:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        aux = _balance_loss(probs, top_e, mo, B)
+
+    y, sizes = per_batch_shard(
+        functools.partial(_routed, first=mo.first_held), (x, top_e, top_w),
+        (params["wg"], params["wi"], params["wo"]))
+    y = y.astype(x.dtype)
     if mo.n_shared_experts:
         y = y + mlp(params["shared"], x)
-    return constrain(y, "batch", "seq", "embed"), aux
+    sizes = jnp.sum(sizes, axis=0)
+    counters = {"moe_routed_held": jnp.sum(sizes),
+                "moe_max_load": jnp.max(sizes)}
+    return constrain(y, "batch", "seq", "embed"), aux, counters

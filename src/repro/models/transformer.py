@@ -71,16 +71,19 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 def _layer_apply(lp, cfg: ModelConfig, x, positions, is_dense_ffn: bool,
                  cache=None, cache_index=None):
+    """-> (x, aux loss, counters, new cache); counters are the MoE layer's
+    (`L.MOE_COUNTERS`), {} for a dense FFN."""
     attn_fn = L.mla_attention if cfg.mla is not None else L.attention
     h, new_cache = attn_fn(lp["attn"], cfg, L.rmsnorm(lp["ln1"], x, cfg.norm_eps),
                            positions, cache, cache_index)
     x = x + h
     ffn_in = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
     if "moe" in lp and not is_dense_ffn:
-        y, aux = L.moe(lp["moe"], cfg, ffn_in)
+        y, aux, counters = L.moe(lp["moe"], cfg, ffn_in)
     else:
-        y, aux = L.mlp(lp["mlp"], ffn_in), jnp.zeros((), jnp.float32)
-    return x + y, aux, new_cache
+        y, aux, counters = (L.mlp(lp["mlp"], ffn_in),
+                            jnp.zeros((), jnp.float32), {})
+    return x + y, aux, counters, new_cache
 
 
 def _remat(fn, cfg: ModelConfig):
@@ -116,6 +119,16 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
     """tokens: (B,S) int32 (or input_embeds (B,S,d) for stubbed frontends).
     positions: (B,S) or (3,B,S) for M-RoPE. Returns logits (B,S,V) and aux loss.
     """
+    logits, aux, _ = forward_with_counters(params, cfg, tokens, positions,
+                                           input_embeds)
+    return logits, aux
+
+
+def forward_with_counters(params, cfg: ModelConfig, tokens, positions=None,
+                          input_embeds=None):
+    """`forward`, and the MoE layers' counters: assignments computed here,
+    summed over the layers, and the largest held expert's load in any
+    layer ({} without expert layers)."""
     if input_embeds is not None:
         x = input_embeds.astype(cfg.dtype)
         B, S = x.shape[:2]
@@ -131,22 +144,28 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
     aux_total = jnp.zeros((), jnp.float32)
     if "dense_layers" in params:
         def dense_body(x, lp):
-            x, aux, _ = _layer_apply(lp, cfg, x, positions, is_dense_ffn=True)
+            x, aux, _, _ = _layer_apply(lp, cfg, x, positions,
+                                        is_dense_ffn=True)
             return x, aux
         x, auxs = scan_layers(dense_body, x, params["dense_layers"], cfg)
         aux_total = aux_total + jnp.sum(auxs)
 
     def body(x, lp):
-        x, aux, _ = _layer_apply(lp, cfg, x, positions, is_dense_ffn=False)
-        return x, aux
+        x, aux, counters, _ = _layer_apply(lp, cfg, x, positions,
+                                           is_dense_ffn=False)
+        return x, (aux, counters)
 
-    x, auxs = scan_layers(body, x, params["layers"], cfg)
+    x, (auxs, per_layer) = scan_layers(body, x, params["layers"], cfg)
     aux_total = aux_total + jnp.sum(auxs)
+    counters = {}
+    if per_layer:
+        counters = {"moe_routed_held": jnp.sum(per_layer["moe_routed_held"]),
+                    "moe_max_load": jnp.max(per_layer["moe_max_load"])}
 
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     logits = x @ head.astype(cfg.dtype)
-    return constrain(logits, "batch", "seq", "vocab"), aux_total
+    return constrain(logits, "batch", "seq", "vocab"), aux_total, counters
 
 
 # ---------------------------------------------------------------------------
@@ -215,8 +234,8 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, index):
     def scan_group(x, group_params, group_cache, dense):
         def body(x, lp_and_cache):
             lp, lc = lp_and_cache
-            x, _, new_c = _layer_apply(lp, cfg, x, pos, dense,
-                                       cache=lc, cache_index=index)
+            x, _, _, new_c = _layer_apply(lp, cfg, x, pos, dense,
+                                          cache=lc, cache_index=index)
             return x, new_c
         return scan_layers(body, x, (group_params, group_cache), cfg)
 

@@ -80,6 +80,38 @@ def test_each_train_step_holds_data_dispatch_sync_observe(trainer,
     assert len(names(ev, "train.data")) == 3
 
 
+def test_an_expert_model_puts_its_routing_counters_on_the_sync_span(
+        tmp_path):
+    """DeepSeek-V2's smoke block: 2 expert layers, every expert held,
+    top-2, so each step computes 2 x tokens x 2 assignments. The counters
+    ride the loss's transfer onto `train.sync` and into the `step` event;
+    a dense model's `train.sync` carries none."""
+    cfg = get_config("deepseek-v2-lite-16b", smoke=True)
+    run = RunConfig(total_steps=40, warmup_steps=2, checkpoint_interval=0,
+                    checkpoint_dir=str(tmp_path / "ckpt"), zero1=False)
+    steps = []
+    tr = TransientTrainer(cfg, run, ShardedLoader(
+        SyntheticTokenSource(cfg.vocab_size, 16), 4),
+        on_event=lambda k, p: steps.append(p) if k == "step" else None)
+    state, _ = tr.restore_or_init()
+    state, _ = tr.run_steps(state, 1)
+    ev = traced(tmp_path, lambda: tr.run_steps(state, 2))
+    syncs = names(ev, "train.sync")
+    assert len(syncs) == 2
+    for sync in syncs:
+        assert sync[3]["moe_routed_held"] == 2 * 4 * 16 * 2
+        assert 0 < sync[3]["moe_max_load"] <= 4 * 16
+    assert [p["moe_routed_held"] for p in steps] == [2 * 4 * 16 * 2] * 3
+
+
+def test_a_dense_model_sync_span_has_no_counters(trainer, tmp_path):
+    tr = trainer["trainer"]
+    ev = traced(tmp_path, lambda: trainer.update(
+        state=tr.run_steps(trainer["state"], 1)[0]))
+    sync, = names(ev, "train.sync")
+    assert not set(sync[3]) & {"moe_routed_held", "moe_max_load"}
+
+
 def test_save_and_restore_write_checkpoint_spans(trainer, tmp_path):
     tr, state = trainer["trainer"], trainer["state"]
     got = {}
