@@ -224,6 +224,71 @@ def test_chunked_attention_takes_a_scale_and_recomputes_each_chunk():
     assert "remat" in jaxpr or "checkpoint" in jaxpr
 
 
+@pytest.mark.parametrize("Sq,causal,want,blocks", [
+    (8192, True, tuple(range(1024, 8193, 1024)), 36),
+    (8192, False, (8192,) * 8, 64),
+    (512, True, (512,), 1),
+])
+def test_key_extents_leave_out_the_masked_blocks(Sq, causal, want, blocks):
+    """At 8k with 1,024-query chunks the causal plan scores 36 of the 64
+    key blocks; without the mask every block; Sq <= chunk is one block."""
+    got = L.key_extents(Sq, Sq, causal, 0, 1024)
+    assert got == want
+    assert sum(-(-e // 1024) for e in got) == blocks
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_chunks_with_key_extents_equal_one_block(causal):
+    """At Sq = 4 x chunk each chunk, scored against its own key extent,
+    gives what one unchunked block gives, value and gradient of q, k, v."""
+    B, S, H, KV, hd, chunk = 1, 64, 4, 2, 8, 16
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, KV, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, KV, hd), jnp.float32)
+    scale = 0.3
+
+    def chunked(q, k, v):
+        return L._chunked_attn(q, k, v, causal, 0, scale, chunk=chunk)
+
+    def whole(q, k, v):
+        return L._attn_block(q.reshape(B, S, KV, H // KV, hd), k, v, causal,
+                             0, 0, scale).reshape(B, S, H, hd)
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(chunked(q, k, v), whole(q, k, v),
+                                   rtol=1e-5, atol=1e-6)
+        loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))  # noqa: E731
+        g1 = jax.grad(loss(chunked), argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(loss(whole), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def test_first_chunk_scores_only_its_own_keys():
+    """Under the causal mask chunk 0's score matmul takes `chunk` keys, not
+    Sk; the last chunk takes all Sk."""
+    B, S, H, KV, hd, chunk = 1, 64, 4, 2, 8, 16
+    q = jnp.zeros((B, S, H, hd), jnp.float32)
+    k = jnp.zeros((B, S, KV, hd), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v: L._chunked_attn(q, k, v, True, 0, 0.3, chunk=chunk)
+    )(q, k, k)
+
+    def key_lengths(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                # the key (or value) operand: (B, extent, KV, hd)
+                yield from (x.aval.shape[1] for x in eqn.invars
+                            if x.aval.ndim == 4)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from key_lengths(sub)
+
+    scored = list(key_lengths(jaxpr.jaxpr))
+    assert scored[0] == chunk
+    assert sorted(set(scored)) == [16, 32, 48, 64]
+
+
 def test_short_attention_path_is_unchanged():
     """Sq <= chunk (Qwen3's 512-token path) is one block at 1/sqrt(hd),
     bit for bit, whether the scale is given or left to its default."""
