@@ -12,21 +12,10 @@ job uploads it as an artifact):
   `plan_launch`. The headline number is the speedup at equal sample
   counts.
 * **simulation ensemble** — `FleetSim.run_many` trajectory throughput for
-  a 4-worker V100 cluster, vs the pre-ensemble pattern of re-building a
-  simulator per seed in a Python loop (what `benchmarks/cross_provider.py`
-  did before the ensemble API).
-* **batched engine** — the lockstep array engine vs the per-trajectory
-  event loop at n=1024 trajectories of the same workload, both consuming
-  identical `FleetDraws` streams so the comparison is work-for-work. The
-  `speedup` here is the regression-gated metric (machine-normalized:
-  both engines run on the same box) with a 10x absolute floor — the
-  acceptance bar of the lockstep-engine PR.
-* **jit engine** — the compiled `engine="jit"` program vs the NumPy
-  lockstep engine at n=65536 trajectories of a chaos mega-ensemble
-  (`regional_wave`), shared draws, raw array stats on both sides,
-  steady-state (compile/pool residency excluded). Regression-gated like
-  the batched entry, with a 5x absolute floor — the acceptance bar of
-  the jit-engine PR.
+  a 4-worker V100 cluster on its default engine (one call, compile
+  included), vs the pre-ensemble pattern of re-building a simulator per
+  seed in a Python loop (what `benchmarks/cross_provider.py` did before
+  the ensemble API). Informational, not gated.
 """
 from __future__ import annotations
 
@@ -61,9 +50,6 @@ N_WORKERS = 4
 SAMPLES = 200
 HOURS = [0, 3, 6, 9, 12, 15, 18, 21]
 ENSEMBLE_N = 64
-BATCHED_N = 1024
-JIT_N = 65536
-JIT_SCENARIO = "regional_wave"
 
 
 # ------------------------------------------------- pinned scalar baseline
@@ -199,95 +185,13 @@ def bench_ensemble(n: int = ENSEMBLE_N) -> dict:
     }
 
 
-def bench_batched_engine(n: int = BATCHED_N) -> dict:
-    """Lockstep array engine vs the event-loop oracle, work-for-work
-    (shared `FleetDraws`), at ensemble scale — the regression-gated
-    hot path of the lockstep-engine PR."""
-    gens = calibrate_generators()
-    c_m = TABLE1_MODELS["resnet_32"]
-    sp = 1.0 / gens["v100"].step_time(c_m)
-    steps = 100_000
-
-    def mk():
-        workers = [SimWorker(i, "v100", "us-central1", sp)
-                   for i in range(N_WORKERS)]
-        return FleetSim(workers, model_gflops=c_m, model_bytes=1.87e6,
-                        step_speed_of=lambda g: sp,
-                        checkpoint_interval_steps=I_C, checkpoint_time_s=T_C,
-                        seed=0, price_of={"v100": 0.74})
-
-    batched_s = _best_of(lambda: mk().run_many(steps, n, max_hours=100.0,
-                                               engine="batched"))
-    event_s = _best_of(lambda: mk().run_many(steps, n, max_hours=100.0,
-                                             engine="event"), reps=2)
-    return {
-        "trajectories": n, "steps": steps,
-        "batched_s": round(batched_s, 4), "event_s": round(event_s, 4),
-        "traj_per_s": round(n / batched_s, 1),
-        "event_traj_per_s": round(n / event_s, 1),
-        "speedup": round(event_s / batched_s, 1),
-    }
-
-
-def bench_jit_engine(n: int = JIT_N) -> dict:
-    """Compiled jit engine vs the NumPy lockstep engine, work-for-work
-    (shared `FleetDraws`, `raw=True` array stats on both sides so neither
-    pays the 65k-`SimResult` construction) on a chaos mega-ensemble —
-    the workload the jit engine exists for. A chaos timeline's fault
-    windows are *global* event stops: every trajectory processes every
-    boundary, which defeats the NumPy engine's shrinking active set and
-    leaves it re-walking full-width rounds under the per-round Python
-    transform overhead, while the compiled `lax.while_loop` fuses them.
-    Parity is asserted in-bench (identical revocation counts) so the
-    timed programs provably do the same work. Engine warm-up (XLA
-    compilation, device pool residency, FleetDraws level materialization)
-    happens before timing: the measurement is steady-state re-scoring
-    throughput, the planner-loop regime (docs/performance.md)."""
-    import jax
-
-    from repro.api.session import Session
-    from repro.chaos.scenarios import get_scenario
-    from repro.core.transient.fleet_batched import FleetDraws, run_batched
-    from repro.core.transient.fleet_jit import run_jit
-
-    sc = get_scenario(JIT_SCENARIO)
-    ses = Session.from_arch("qwen3-1.7b", smoke=True)
-    sim, n_steps = ses._fleet_sim(
-        n_workers=sc.n_workers, gpu=sc.gpu, region=sc.region,
-        steps=sc.total_steps, seed=0, handover=sc.handover,
-        provider=sc.provider)
-    sim.chaos = sc.timeline(sim._roster, seed=0)
-    draws = FleetDraws(sim, n, 0.0)
-    args = (n_steps, n, sc.max_hours, 0.0)
-    rb = run_batched(sim, *args, draws=draws, raw=True)
-    rj = run_jit(sim, *args, draws=draws, raw=True)
-    if not (rb["revocations"] == rj["revocations"]).all():
-        raise AssertionError(
-            "engine parity violated inside bench_jit_engine — the timed "
-            "engines are not doing identical work")
-    batched_s = _best_of(lambda: run_batched(sim, *args, draws=draws,
-                                             raw=True), reps=2)
-    jit_s = _best_of(lambda: run_jit(sim, *args, draws=draws, raw=True))
-    return {
-        "trajectories": n, "scenario": JIT_SCENARIO, "steps": n_steps,
-        "devices": len(jax.devices()),
-        "batched_s": round(batched_s, 4), "jit_s": round(jit_s, 4),
-        "traj_per_s": round(n / jit_s, 1),
-        "speedup": round(batched_s / jit_s, 1),
-    }
-
-
 def run():
     grid = bench_planner_grid()
     ens = bench_ensemble()
-    eng = bench_batched_engine()
-    jit = bench_jit_engine()
     payload = {
-        "schema": 2,
+        "schema": 3,
         "planner_grid": grid,
         "ensemble": ens,
-        "batched_engine": eng,
-        "jit_engine": jit,
     }
     OUT_PATH.write_text(json.dumps(payload, indent=2) + "\n")
     return [
@@ -303,19 +207,6 @@ def run():
                      f"{ens['batched_s']}s (loop: {ens['loop_s']}s); "
                      f"p50={ens['time_p50_s']}s p90={ens['time_p90_s']}s "
                      f"E[rev]={ens['revocations_mean']} (traj/s)")},
-        {"name": f"mc_speed/batched_engine/v100x4/n{eng['trajectories']}",
-         "value": eng["speedup"],
-         "derived": (f"{eng['trajectories']} trajectories: event "
-                     f"{eng['event_s']}s ({eng['event_traj_per_s']} traj/s)"
-                     f" -> batched {eng['batched_s']}s "
-                     f"({eng['traj_per_s']} traj/s) (speedup x)")},
-        {"name": (f"mc_speed/jit_engine/{jit['scenario']}/"
-                  f"n{jit['trajectories']}"),
-         "value": jit["speedup"],
-         "derived": (f"{jit['trajectories']} chaos trajectories on "
-                     f"{jit['devices']} device(s): batched "
-                     f"{jit['batched_s']}s -> jit {jit['jit_s']}s "
-                     f"({jit['traj_per_s']} traj/s) (speedup x)")},
     ]
 
 

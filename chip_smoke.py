@@ -7,9 +7,9 @@ One process, through the same `Session` entry points as `python -m repro`:
 
   kernels  each Pallas kernel once at real widths, against its
            `kernels/ref.py` oracle, with the Mosaic call in the program
-  fleet    `Session.simulate(engine="jit")` under the regional_wave chaos
-           timeline, and exact revocation/replacement parity with the
-           batched engine on the same draws
+  fleet    `Session.simulate` (the jit fleet engine) under the
+           regional_wave chaos timeline, and exact revocation/replacement
+           parity with the event loop on the same draws
   train    qwen3-1.7b at its published widths (d_model 2048, 16 q / 8 kv
            heads x 128, d_ff 6144, vocab 151936, tied embeddings), depth
            cut to LAYERS; AdamW with f32 state; a few steps, a checkpoint
@@ -176,9 +176,10 @@ def phase_kernels(seed: int) -> None:
 
 # ------------------------------------------------------------------ fleet
 def fleet_simulate():
-    """A session and its `simulate` under the SCENARIO chaos timeline, with
-    the fleet sized for the smoke model's step time, as in
-    benchmarks/mc_speed.bench_jit_engine."""
+    """A session, its `simulate` under the SCENARIO chaos timeline (the
+    fleet sized for the smoke model's step time), and the event-loop
+    oracle: `run_many(n, engine="event")` on the sim `simulate` builds,
+    under the same timeline."""
     from repro.api import Session
     from repro.chaos.scenarios import get_scenario
 
@@ -187,28 +188,31 @@ def fleet_simulate():
     kw = dict(n_workers=sc.n_workers, gpu=sc.gpu, region=sc.region,
               steps=sc.total_steps, seed=0, handover=sc.handover,
               provider=sc.provider)
-    sim, _ = s._fleet_sim(**kw)
-    return s, functools.partial(s.simulate, **kw, max_hours=sc.max_hours,
-                                chaos=sc.timeline(sim._roster, seed=0))
+    sim, n_steps = s._fleet_sim(**kw)
+    sim.chaos = sc.timeline(sim._roster, seed=0)
+    return (s, functools.partial(s.simulate, **kw, max_hours=sc.max_hours,
+                                 chaos=sim.chaos),
+            functools.partial(sim.run_many, n_steps, max_hours=sc.max_hours,
+                              engine="event"))
 
 
 def phase_fleet(n: int, phase: str = "fleet") -> None:
-    s, run = fleet_simulate()
+    s, run, oracle = fleet_simulate()
     counts = {}
-    for engine in ("batched", "jit"):
-        ens = run(samples=PARITY_N, engine=engine)
+    for engine, ens in (("jit", run(samples=PARITY_N)),
+                        ("event", oracle(PARITY_N))):
         counts[engine] = [(r.revocations, r.replacements)
                           for r in ens.results]
-    check(counts["jit"] == counts["batched"],
-          "jit and batched engines disagree on revocation/replacement "
-          "counts over the same draws")
+    check(counts["jit"] == counts["event"],
+          "the jit engine and the event loop disagree on "
+          "revocation/replacement counts over the same draws")
     check(sum(r for r, _ in counts["jit"]) > 0,
           f"{SCENARIO} revoked no worker in {PARITY_N} trajectories")
 
     walls = []
     for _ in range(2):                 # cold (compiles), then warm
         t0 = time.monotonic()
-        ens = run(samples=n, engine="jit")
+        ens = run(samples=n)
         walls.append(time.monotonic() - t0)
     times = np.array([r.total_time_s for r in ens.results])
     check(len(times) == n and bool(np.all(np.isfinite(times))),

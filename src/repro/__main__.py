@@ -5,7 +5,7 @@
     python -m repro plan     [--arch ...] --gpu v100 --workers 4 [--provider aws]
     python -m repro simulate [--arch ...] --gpu v100 --workers 4 [--provider azure]
     python -m repro predict  [--arch ...] --gpu v100 --workers 4 [--provider gcp]
-    python -m repro chaos    --scenario all [--engine batched|event|jit] [--live]
+    python -m repro chaos    --scenario all [--live]
     python -m repro bench    --only table1_speed,fig2_stability
     python -m repro dryrun   --arch qwen3-1.7b --shape train_4k
 
@@ -75,10 +75,6 @@ def build_parser() -> argparse.ArgumentParser:
                                 "(default) or a full fleet-simulation "
                                 "ensemble per cell with time/cost "
                                 "percentiles")
-            q.add_argument("--engine", default="batched",
-                           choices=("batched", "event", "jit"),
-                           help="trajectory stepper for --score sim "
-                                "(docs/performance.md)")
             # planning is uncapped unless the user asks for the Fig 4 PS
             # model (--score sim always applies it, with 1 PS by default)
             q.set_defaults(n_ps=None)
@@ -86,12 +82,6 @@ def build_parser() -> argparse.ArgumentParser:
             q.add_argument("--samples", type=int, default=1,
                            help="trajectories; >1 reports the p50/p90/mean "
                                 "ensemble summary (SimStats)")
-            q.add_argument("--engine", default="batched",
-                           choices=("batched", "event", "jit"),
-                           help="ensemble stepper: lockstep array engine "
-                                "(default), the per-trajectory event "
-                                "loop, or the compiled jit program "
-                                "(docs/performance.md)")
 
     c = sub.add_parser("chaos", help="scripted fault scenarios with "
                                      "ground-truth-scored detection & "
@@ -102,10 +92,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="registered scenario name, or 'all' (default)")
     c.add_argument("--list", action="store_true",
                    help="list registered scenarios and exit")
-    c.add_argument("--engine", default="batched",
-                   choices=("batched", "event", "jit"),
-                   help="fleet-ensemble stepper (an engine-vs-event "
-                        "parity probe runs either way)")
     c.add_argument("--live", action=argparse.BooleanOptionalAction,
                    default=True,
                    help="drive the real trainer through scenarios that "
@@ -228,8 +214,7 @@ def _cmd_plan(args) -> int:
                                checkpoint_interval=args.checkpoint_interval,
                                region=args.region, seed=args.seed,
                                provider=args.provider, samples=args.samples,
-                               score=args.score, engine=args.engine,
-                               n_ps=args.n_ps)
+                               score=args.score, n_ps=args.n_ps)
     where = args.region or "all regions"
     what = ("simulated trajectories" if args.score == "sim" else "samples")
     print(f"arch={session.arch} provider={args.provider} gpu={args.gpu} "
@@ -255,8 +240,7 @@ def _cmd_simulate(args) -> int:
                            region=args.region, steps=args.steps,
                            checkpoint_interval=args.checkpoint_interval,
                            n_ps=args.n_ps, seed=args.seed,
-                           provider=args.provider, samples=args.samples,
-                           engine=args.engine)
+                           provider=args.provider, samples=args.samples)
     if args.samples > 1:
         st = res.stats
         print(f"arch={session.arch} {args.workers}x{args.gpu} on "
@@ -310,9 +294,9 @@ def _cmd_chaos(args) -> int:
         print("\n".join(list_scenarios()))
         return 0
     session = cli.session_from_args(args)
-    card = run_scenarios(args.scenario, session=session, engine=args.engine,
-                         live=args.live, samples=args.samples,
-                         seed=args.seed, smoke=args.smoke,
+    card = run_scenarios(args.scenario, session=session, live=args.live,
+                         samples=args.samples, seed=args.seed,
+                         smoke=args.smoke,
                          progress=lambda m: print(m, file=sys.stderr))
     print(json.dumps(card, indent=2, sort_keys=True))
     if args.smoke and not card["passed"]:

@@ -215,7 +215,6 @@ class Session:
              samples: int = 200,
              n_ps: Optional[int] = None,
              score: str = "eq4",
-             engine: str = "batched",
              resilience: Optional[object] = None
              ) -> Tuple[LaunchPlan, List[LaunchPlan]]:
         """Revocation-aware (region, launch-hour) planning for this model.
@@ -231,11 +230,10 @@ class Session:
         so a compressed plan sees the raised ceiling.
 
         `score="sim"` replaces the Eq (4) point estimate with a full
-        fleet-simulation ensemble per cell (`samples` trajectories on
-        `engine` — "batched", "event", or "jit"), so every plan also
-        carries realized
-        time/cost percentiles and the `finished` censoring count —
-        simulation-backed planning instead of the closed form alone.
+        fleet-simulation ensemble per cell (`samples` trajectories), so
+        every plan also carries realized time/cost percentiles and the
+        `finished` censoring count — simulation-backed planning instead
+        of the closed form alone.
         A sim-scored sweep ALWAYS simulates under the Fig 4 PS capacity
         for this model (defaulting to one PS when `n_ps` is not given),
         matching what `simulate()`/`predict()` would report for the
@@ -266,7 +264,7 @@ class Session:
             # the session's real model complexity, so plan() and predict()
             # agree on the Fig 10 replacement term for the same cell
             model_gflops=self.model_gflops(), ps=ps,
-            score=score, engine=engine, model_bytes=self.model_bytes(),
+            score=score, model_bytes=self.model_bytes(),
             # constrain BEFORE scoring: under score="sim" every discarded
             # cell would have cost a full ensemble
             region=region,
@@ -286,7 +284,6 @@ class Session:
                  provider: Optional[object] = None,
                  start_hour: float = 0.0,
                  samples: int = 1,
-                 engine: str = "batched",
                  chaos: object = None,
                  resilience: Optional[object] = None):
         """Discrete-event simulation on a transient cluster.
@@ -300,12 +297,7 @@ class Session:
         `SimResult`, bit-identical to the pre-ensemble behavior for a
         fixed seed. `samples>1` runs a `FleetSim.run_many` ensemble and
         returns a `FleetEnsemble` whose `.stats` is the p50/p90/mean
-        `SimStats` summary; `engine` picks the trajectory stepper —
-        "batched" (default) is the lockstep array engine, "event" the
-        per-trajectory discrete-event loop kept as the parity oracle,
-        "jit" the same lockstep rounds compiled into one jitted JAX
-        program for mega-ensembles (docs/performance.md has the
-        selection guide).
+        `SimStats` summary (docs/performance.md, "Fleet engines").
 
         The simulated PS capacity uses this model's variable count and
         `run.grad_compression`, exactly like `Session.predict` — so
@@ -319,7 +311,7 @@ class Session:
         `resilience` (a `repro.resilience.ResilienceConfig`; default: the
         session `run.resilience`) arms quorum degradation and
         restore-retry stalls in the simulated fleet (docs/resilience.md)
-        — identically on every engine.
+        — identically in both fleet engines.
         """
         sim, n_steps = self._fleet_sim(
             n_workers=n_workers, gpu=gpu, region=region, counts=counts,
@@ -328,7 +320,7 @@ class Session:
             provider=provider, chaos=chaos, resilience=resilience)
         if samples > 1:
             return sim.run_many(n_steps, samples, max_hours=max_hours,
-                                start_hour=start_hour, engine=engine)
+                                start_hour=start_hour)
         return sim.run(n_steps, max_hours=max_hours, start_hour=start_hour)
 
     def _fleet_sim(self, *, n_workers: int = 4, gpu: str = "v100",
@@ -378,8 +370,8 @@ class Session:
         return sim, n_steps
 
     # ---------------------------------------------------- chaos scenarios
-    def chaos(self, scenario: str = "all", *, engine: str = "batched",
-              live: bool = True, samples: int = 32, seed: int = 0,
+    def chaos(self, scenario: str = "all", *, live: bool = True,
+              samples: int = 32, seed: int = 0,
               smoke: bool = False) -> Dict[str, object]:
         """Run scripted fault scenarios against this model and score the
         detection/mitigation loop against the recorded ground truth.
@@ -387,8 +379,7 @@ class Session:
         `scenario` is a registered scenario name (see
         `repro.chaos.list_scenarios()`) or `"all"`. Each scenario runs as
         a fleet-simulation ensemble (`samples` faulted + baseline
-        trajectories on `engine` — "batched", "event" or "jit" — plus an
-        engine-vs-event parity probe);
+        trajectories, plus a parity probe against the event loop);
         scenarios with a live plan additionally drive the real
         `TransientTrainer` under a virtual clock (`live=False` skips
         that). `smoke=True` also checks each scenario's `expect` gates
@@ -399,8 +390,8 @@ class Session:
         """
         from repro.chaos import runner as chaos_runner
         return chaos_runner.run_scenarios(
-            scenario, session=self, engine=engine, live=live,
-            samples=samples, seed=seed, smoke=smoke)
+            scenario, session=self, live=live, samples=samples, seed=seed,
+            smoke=smoke)
 
     # ------------------------------------------------ Eq (4)/(5) predict
     def predict(self, n_workers: int = 4, gpu: str = "v100",

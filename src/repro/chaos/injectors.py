@@ -12,7 +12,7 @@ magnitude, all relative to launch (hours of elapsed sim time) — and the
     They act on *lifetimes*, not on the clock: every drawn lifetime is
     deterministically transformed by an inverse-CDF thinning of the
     window overlap, using draws keyed on (seed, fault, trajectory, slot,
-    generation) — so the batched and event engines see bit-identical
+    generation) — so the jit and event engines see bit-identical
     revocation timelines no matter in which order they consume them.
   * `StragglerFault` — silently scales one roster slot's step speed
     (degraded NIC / thermal throttling; Table III heterogeneity gone bad).

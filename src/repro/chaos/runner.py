@@ -3,11 +3,11 @@
 `run_scenarios` is what `Session.chaos` and `python -m repro chaos` call.
 Per scenario it produces one JSON-serializable scorecard:
 
-* **sim** — a faulted vs baseline fleet-simulation ensemble on the
-  requested engine (recovery cost in wall-clock, $ and lost steps), a
-  batched-vs-event *parity probe* (same `FleetDraws`-keyed fault
-  transforms must give identical per-trajectory revocation/replacement
-  counts and matching times on both engines), and the ground-truth
+* **sim** — a faulted vs baseline fleet-simulation ensemble (recovery
+  cost in wall-clock, $ and lost steps), a jit-vs-event *parity probe*
+  (same `FleetDraws`-keyed fault transforms must give identical
+  per-trajectory revocation/replacement counts and matching times on
+  both engines), and the ground-truth
   timeline plus a hash of the hazard-transformed lifetime matrix — the
   bit-identical-across-engines contract, pinned.
 * **live** (scenarios with a `LivePlan`, unless `live=False`) — the real
@@ -72,9 +72,9 @@ def _ens_summary(ens) -> Dict[str, float]:
                 [r.restore_delay_s for r in ens.results])), 6)}
 
 
-def _run_sim(session, sc: Scenario, engine: str, samples: int,
+def _run_sim(session, sc: Scenario, samples: int,
              seed: int) -> Dict[str, object]:
-    from repro.core.transient.fleet_batched import FleetDraws
+    from repro.core.transient.draws import FleetDraws
 
     def build(chaos: bool):
         sim, n_steps = session._fleet_sim(
@@ -95,18 +95,14 @@ def _run_sim(session, sc: Scenario, engine: str, samples: int,
     h.update(np.ascontiguousarray(draws.initial).tobytes())
     truth_hash = h.hexdigest()
 
-    faulted = sim_f.run_many(n_steps, samples, max_hours=sc.max_hours,
-                             engine=engine)
+    faulted = sim_f.run_many(n_steps, samples, max_hours=sc.max_hours)
     baseline = build(chaos=False)[0].run_many(
-        n_steps, samples, max_hours=sc.max_hours, engine=engine)
+        n_steps, samples, max_hours=sc.max_hours)
 
     # two-engine parity probe on a small slice of the ensemble: the
-    # requested engine (falling back to "batched" when the requested one
-    # *is* the oracle) vs. the per-trajectory event loop
-    probe = engine if engine != "event" else "batched"
+    # default engine vs. the per-trajectory event loop
     pa = build(chaos=True)[0].run_many(n_steps, PARITY_SAMPLES,
-                                       max_hours=sc.max_hours,
-                                       engine=probe)
+                                       max_hours=sc.max_hours)
     pb = build(chaos=True)[0].run_many(n_steps, PARITY_SAMPLES,
                                        max_hours=sc.max_hours,
                                        engine="event")
@@ -120,7 +116,7 @@ def _run_sim(session, sc: Scenario, engine: str, samples: int,
 
     fs, bs = _ens_summary(faulted), _ens_summary(baseline)
     return {
-        "engine": engine, "samples": samples,
+        "samples": samples,
         "truth": truth, "truth_hash": truth_hash,
         "faulted": fs, "baseline": bs,
         "impact": {
@@ -131,7 +127,7 @@ def _run_sim(session, sc: Scenario, engine: str, samples: int,
             "extra_lost_steps": round(fs["lost_steps_mean"]
                                       - bs["lost_steps_mean"], 6),
         },
-        "parity": {"trajectories": PARITY_SAMPLES, "engine": probe,
+        "parity": {"trajectories": PARITY_SAMPLES,
                    "counts_equal": counts_equal,
                    "time_max_rel_err": time_err},
     }
@@ -142,7 +138,7 @@ def _serving_summary(results) -> Dict[str, object]:
     return summarize_serving(results)
 
 
-def _run_serving(session, sc: Scenario, engine: str, samples: int,
+def _run_serving(session, sc: Scenario, samples: int,
                  seed: int) -> Dict[str, object]:
     """Serving-fleet scorecard for scenarios carrying a `ServingScript`.
 
@@ -168,16 +164,14 @@ def _run_serving(session, sc: Scenario, engine: str, samples: int,
             batch_ceiling=spec.batch_ceiling, horizon_s=spec.horizon_s,
             seed=seed)
 
-    run_engine = engine if engine in ("batched", "event") else "batched"
-    armed = build(True, armed_cfg).run_many(samples, engine=run_engine)
-    stock = build(True, None).run_many(samples, engine=run_engine)
-    baseline = build(False, armed_cfg).run_many(samples, engine=run_engine)
+    armed = build(True, armed_cfg).run_many(samples)
+    stock = build(True, None).run_many(samples)
+    baseline = build(False, armed_cfg).run_many(samples)
 
     # two-engine parity probe, same contract as the training sims: the
     # batched candidate-array engine and the per-trajectory event heap
     # must agree on every count and every latency
-    probe = "batched" if run_engine == "event" else run_engine
-    pa = build(True, armed_cfg).run_many(PARITY_SAMPLES, engine=probe)
+    pa = build(True, armed_cfg).run_many(PARITY_SAMPLES)
     pb = build(True, armed_cfg).run_many(PARITY_SAMPLES, engine="event")
     counts_equal = all(
         (a.completed, a.shed, a.dropped_inflight, a.dropped_warned,
@@ -201,13 +195,13 @@ def _run_serving(session, sc: Scenario, engine: str, samples: int,
                        / max(b.total_time_s, 1e-9))
 
     return {
-        "engine": run_engine, "samples": samples,
+        "samples": samples,
         "replicas": spec.replicas,
         "armed": _serving_summary(armed),
         "stock": _serving_summary(stock),
         "baseline": _serving_summary(baseline),
         "impact": score_serving(armed, stock, baseline),
-        "parity": {"trajectories": PARITY_SAMPLES, "engine": probe,
+        "parity": {"trajectories": PARITY_SAMPLES,
                    "counts_equal": counts_equal,
                    "time_max_rel_err": time_err},
     }
@@ -457,8 +451,8 @@ def _check_expectations(sc: Scenario, card: Dict[str, object]) -> List[str]:
     return fails
 
 
-def run_scenario(sc: Scenario, *, session=None, engine: str = "batched",
-                 live: bool = True, samples: int = 32, seed: int = 0,
+def run_scenario(sc: Scenario, *, session=None, live: bool = True,
+                 samples: int = 32, seed: int = 0,
                  smoke: bool = False) -> Dict[str, object]:
     """One scenario -> one scorecard dict (see the module docstring)."""
     if session is None:
@@ -471,8 +465,8 @@ def run_scenario(sc: Scenario, *, session=None, engine: str = "batched",
         # serving scenarios script faults over a ReplicaSet, not a
         # training fleet — the per-worker training sim would be noise
         "sim": (None if sc.serving is not None
-                else _run_sim(session, sc, engine, samples, seed)),
-        "serving": (_run_serving(session, sc, engine, samples, seed)
+                else _run_sim(session, sc, samples, seed)),
+        "serving": (_run_serving(session, sc, samples, seed)
                     if sc.serving is not None else None),
         "live": (_run_live(session, sc, seed)
                  if live and sc.live is not None else None),
@@ -484,8 +478,8 @@ def run_scenario(sc: Scenario, *, session=None, engine: str = "batched",
 
 
 def run_scenarios(scenario: str = "all", *, session=None,
-                  engine: str = "batched", live: bool = True,
-                  samples: int = 32, seed: int = 0, smoke: bool = False,
+                  live: bool = True, samples: int = 32, seed: int = 0,
+                  smoke: bool = False,
                   progress: Optional[Callable[[str], None]] = None
                   ) -> Dict[str, object]:
     """Run one registered scenario (or all of them) -> full scorecard."""
@@ -495,10 +489,9 @@ def run_scenarios(scenario: str = "all", *, session=None,
         if progress:
             progress(f"chaos: running scenario {name}")
         cards[name] = run_scenario(get_scenario(name), session=session,
-                                   engine=engine, live=live,
-                                   samples=samples, seed=seed, smoke=smoke)
-    out = {"engine": engine, "samples": samples, "seed": seed,
-           "scenarios": cards}
+                                   live=live, samples=samples, seed=seed,
+                                   smoke=smoke)
+    out = {"samples": samples, "seed": seed, "scenarios": cards}
     if smoke:
         out["passed"] = all(c["smoke"]["passed"] for c in cards.values())
     return out

@@ -72,7 +72,7 @@ def ps_queue_sim(compute_times: Sequence[float], model_bytes: float,
     the incremental ones in the last bits, so two arrivals closer than
     that noise may serve in either order — transient serve-order swaps
     that keep aggregates within ~0.5% for short runs and vanish as
-    steps grow (tests/test_fleet_batched.py fuzzes the bound against a
+    steps grow (tests/test_fleet_engines.py fuzzes the bound against a
     pinned copy of the heap loop). Small heterogeneous populations
     (n <= 8) keep a scalar next-event scan — the array rounds would pay
     ~20 numpy calls per 1-2 served pushes there.

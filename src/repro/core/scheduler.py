@@ -56,7 +56,7 @@ class LaunchPlan:
     #: Monte-Carlo sample count behind the estimate
     samples: int = 0
     #: how the cell was scored: "eq4" (Eq (4) point estimate around a
-    #: lifetime MC) or "sim" (full batched fleet-simulation ensemble)
+    #: lifetime MC) or "sim" (full fleet-simulation ensemble)
     score: str = "eq4"
     #: distribution summary, populated under score="sim" (zeros otherwise)
     time_p50_s: float = 0.0
@@ -116,7 +116,6 @@ def plan_launch(gpu: str, n_workers: int, worker_speed: float,
                 samples: int = 200,
                 ps: Optional[PSBottleneckModel] = None,
                 score: str = "eq4",
-                engine: str = "batched",
                 model_bytes: float = 1.87e6,
                 replace: bool = True,
                 handover: bool = True,
@@ -138,17 +137,17 @@ def plan_launch(gpu: str, n_workers: int, worker_speed: float,
 
     * ``"eq4"`` (default) — the Eq (4) point estimate around one batched
       lifetime draw (+ binomial stderr), exactly the historic planner.
-    * ``"sim"`` — a full `FleetSim.run_many` ensemble per cell on
-      `engine` (`"batched"`/`"event"`/`"jit"`): every plan carries
-      realized time/cost percentiles (`time_p50_s`/`time_p90_s`/
-      `cost_p50`/`cost_p90`), the trajectory-sample revocation stderr and
-      the `finished` censoring count, so the chosen cell reflects the
-      simulated dynamics (chief loss, replacement chains, diurnal join
-      hours) instead of the Eq (4) closed form alone. `model_bytes`,
-      `replace`, `handover` and `max_sim_hours` (default: 6x the
-      no-revocation Eq (4) wall-clock, at least 48 h) shape that
-      simulation; cells share the simulation seed, so they are compared
-      under common random numbers like the eq4 grid.
+    * ``"sim"`` — a full `FleetSim.run_many` ensemble per cell: every
+      plan carries realized time/cost percentiles (`time_p50_s`/
+      `time_p90_s`/`cost_p50`/`cost_p90`), the trajectory-sample
+      revocation stderr and the `finished` censoring count, so the
+      chosen cell reflects the simulated dynamics (chief loss,
+      replacement chains, diurnal join hours) instead of the Eq (4)
+      closed form alone. `model_bytes`, `replace`, `handover` and
+      `max_sim_hours` (default: 6x the no-revocation Eq (4) wall-clock,
+      at least 48 h) shape that simulation; cells share the simulation
+      seed, so they are compared under common random numbers like the
+      eq4 grid.
 
     `ps` (optional) caps the cluster speed with the Fig 4 PS capacity
     model, including its `compression` scheme — a plan made for a
@@ -207,8 +206,7 @@ def plan_launch(gpu: str, n_workers: int, worker_speed: float,
     if score == "sim":
         plans = _sim_scored_grid(
             gpu, n_workers, worker_speed, n_w, i_c, t_c, hours, seed, prov,
-            model_gflops, samples, ps, engine, model_bytes, replace,
-            handover,
+            model_gflops, samples, ps, model_bytes, replace, handover,
             max_sim_hours if max_sim_hours is not None
             else max(48.0, 6.0 * base_s / 3600.0), regions, resilience)
         best = min(plans, key=lambda p: (p.expected_cost, p.expected_time_s))
@@ -241,12 +239,11 @@ def plan_launch(gpu: str, n_workers: int, worker_speed: float,
 
 
 def _sim_scored_grid(gpu, n_workers, worker_speed, n_w, i_c, t_c, hours,
-                     seed, prov, model_gflops, samples, ps, engine,
-                     model_bytes, replace, handover, max_sim_hours,
-                     regions, resilience=None) -> List[LaunchPlan]:
-    """One batched fleet-simulation ensemble per (region, hour) cell —
-    the simulation-backed §V-C planner the lockstep engine makes routine
-    (10k+ trajectories per sweep stay sub-second)."""
+                     seed, prov, model_gflops, samples, ps, model_bytes,
+                     replace, handover, max_sim_hours, regions,
+                     resilience=None) -> List[LaunchPlan]:
+    """One fleet-simulation ensemble per (region, hour) cell — the
+    simulation-backed §V-C planner."""
     from repro.core.transient.fleet import FleetSim, SimWorker
     plans: List[LaunchPlan] = []
     for region in regions:
@@ -267,7 +264,7 @@ def _sim_scored_grid(gpu, n_workers, worker_speed, n_w, i_c, t_c, hours,
                 price_of={gpu: prov.price(gpu)}, provider=prov,
                 resilience=resilience)
             ens = sim.run_many(n_w, samples, max_hours=max_sim_hours,
-                               start_hour=float(h), engine=engine)
+                               start_hour=float(h))
             st = ens.stats
             plans.append(LaunchPlan(
                 region, gpu, h, n_workers,

@@ -3,7 +3,5 @@ from repro.core.transient.revocation import (  # noqa: F401
 )
 from repro.core.transient.startup import StartupModel  # noqa: F401
 from repro.core.transient.replacement import ReplacementModel  # noqa: F401
-from repro.core.transient.fleet import (FleetEvent, FleetSim,  # noqa: F401
-                                        FleetSimulator)
-from repro.core.transient.fleet_batched import (FleetDraws,  # noqa: F401
-                                                run_batched)
+from repro.core.transient.fleet import FleetEvent, FleetSim  # noqa: F401
+from repro.core.transient.draws import FleetDraws  # noqa: F401

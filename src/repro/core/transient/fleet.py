@@ -190,7 +190,7 @@ class FleetSim:
         # a repro.resilience.ResilienceConfig (or None): quorum-tier
         # degradation gates effective speed, and stock-chief restores
         # stall for the keyed retry schedule — honored identically by
-        # all three engines (docs/resilience.md)
+        # both engines (docs/resilience.md)
         self.resilience = resilience
 
     def _respawn(self, seed: int) -> "FleetSim":
@@ -246,17 +246,17 @@ class FleetSim:
         `initial_lifetimes`: pre-drawn lifetimes (hours, launch-roster
         order, np.inf = survived) — `run_many` injects one batched draw
         per trajectory; the default draws from `self.rev` as before.
-        `draws` (a `fleet_batched.FleetDraws`) + `traj` switch every
+        `draws` (a `draws.FleetDraws`) + `traj` switch every
         replacement-chain draw (startup, cold start, join lifetime) onto
-        the counter-based per-(trajectory, slot, generation) streams the
-        batched engine consumes, making this event loop the exact parity
-        oracle for `run_many(engine="batched")`; the default `None`
-        keeps the historic sequential streams bit-for-bit."""
+        the keyed per-(trajectory, slot, generation) streams the jit
+        engine consumes, making this event loop the exact parity oracle
+        for `run_many`; the default `None` keeps the historic sequential
+        streams bit-for-bit."""
         if self.chaos is not None and draws is None:
             # standalone chaos run: route all randomness through the
             # shared-draws streams (n=1), so hazard-transformed lifetimes
             # are identical to run_many(n=1) on either engine
-            from repro.core.transient.fleet_batched import FleetDraws
+            from repro.core.transient.draws import FleetDraws
             draws = FleetDraws(self, 1, start_hour)
             traj = 0
             if initial_lifetimes is None:
@@ -268,7 +268,7 @@ class FleetSim:
             w.wid: (idx, 0) for idx, w in enumerate(self.workers.values())}
         # resilience (docs/resilience.md): restore-retry stalls keyed on
         # (seed, traj, slot, gen) — through the shared draws when present
-        # (parity with the batched/jit engines), else a local n=1 pool
+        # (parity with the jit engine), else a local n=1 pool
         res = self.resilience
         n_slots = len(self._roster)
         if res is not None and res.restore_fail_p > 0.0:
@@ -498,7 +498,7 @@ class FleetSim:
                    for g, secs in gpu_seconds.items())
         regions = {w.region for w in self.workers.values()}
         # steps accumulates float increments, so a completed run can sit
-        # an ulp below total_steps — the same epsilon the batched engine
+        # an ulp below total_steps — the same epsilon the jit engine
         # applies keeps steps_done (and SimStats.finished) truthful
         return SimResult(t, int(steps + 1e-6), revocations, replacements,
                          ckpt_time, recompute, lost, events, cost,
@@ -508,29 +508,23 @@ class FleetSim:
 
     def run_many(self, total_steps: int, n: int, max_hours: float = 48.0,
                  start_hour: float = 0.0, *,
-                 engine: str = "batched") -> FleetEnsemble:
+                 engine: str = "jit") -> FleetEnsemble:
         """Simulate `n` independent trajectories of the same launch.
 
-        All randomness comes from one `fleet_batched.FleetDraws`: initial
-        lifetimes are pre-drawn as a single (n, slots) matrix (one batched
-        `RevocationSampler.lifetimes` call per (region, gpu) roster group,
-        seeded with `self.seed` — the scheme this method has always used),
-        and replacement-chain draws come from counter-based streams keyed
-        on (seed, trajectory, slot, generation). Both engines therefore
-        simulate the *same* trajectories:
+        All randomness comes from one `draws.FleetDraws` (initial
+        lifetimes as one (n, slots) matrix seeded with `self.seed`,
+        replacement-chain draws keyed on (seed, trajectory, slot,
+        generation)), so both engines simulate the *same* trajectories:
 
-        * ``engine="batched"`` (default) — the lockstep array engine
-          (`fleet_batched.run_batched`): all trajectories advance
-          simultaneously, next events found by vectorized min-reductions.
+        * ``engine="jit"`` (default) — the lockstep rounds compiled into
+          one jitted JAX program (`fleet_jit.run_jit`, docs/DESIGN.md §2).
+          A roster whose lifetime law has no jittable port (a custom
+          provider's; `fleet_jit.law_family` is None) runs on the event
+          loop instead.
         * ``engine="event"`` — the per-trajectory discrete-event loop
-          (`run`), kept as the parity oracle; identical
+          (`run`), kept as the parity oracle: identical
           revocation/replacement counts, times equal up to float
           association order.
-        * ``engine="jit"`` — the same lockstep rounds compiled into one
-          jitted JAX program (`fleet_jit.run_jit`): state on device,
-          draws pre-materialized, trajectories sharded across visible
-          devices. Same parity contract; requires a provider whose
-          lifetime law has a jittable port (gcp/aws/azure).
 
         `run(...)` with the same seed remains the single-trajectory path;
         `run_many` never perturbs its streams.
@@ -539,22 +533,21 @@ class FleetSim:
         holds `fleet.draws`, the jit engine's own spans and `fleet.results`
         (docs/performance.md).
         """
-        from repro.core.transient.fleet_batched import FleetDraws, run_batched
+        from repro.core.transient import fleet_jit
+        from repro.core.transient.draws import FleetDraws
         if n < 1:
             raise ValueError(f"need at least one trajectory, got {n}")
-        if engine not in ("batched", "event", "jit"):
+        if engine not in ("jit", "event"):
             raise ValueError(f"unknown engine {engine!r}; "
-                             f"known: ('batched', 'event', 'jit')")
+                             f"known: ('jit', 'event')")
+        if engine == "jit" and fleet_jit.law_family(self) is None:
+            engine = "event"
         with TraceAnnotation("fleet.run_many", n=n):
             with TraceAnnotation("fleet.draws"):
                 draws = FleetDraws(self, n, start_hour)
-            if engine == "batched":
-                results = run_batched(self, total_steps, n, max_hours,
-                                      start_hour, draws=draws)
-            elif engine == "jit":
-                from repro.core.transient.fleet_jit import run_jit
-                results = run_jit(self, total_steps, n, max_hours,
-                                  start_hour, draws=draws)
+            if engine == "jit":
+                results = fleet_jit.run_jit(self, total_steps, n, max_hours,
+                                            start_hour, draws=draws)
             else:
                 results = []
                 for j in range(n):
@@ -569,7 +562,3 @@ class FleetSim:
                     results, SimStats.from_results(results, total_steps),
                     provider=self.provider.name,
                     region=regions.pop() if len(regions) == 1 else "")
-
-
-#: Long-form alias used by the docs and the provider layer.
-FleetSimulator = FleetSim

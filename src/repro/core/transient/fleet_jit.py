@@ -1,14 +1,13 @@
-"""Jitted mega-ensemble fleet engine — the lockstep simulator as ONE
-compiled JAX program (`engine="jit"`).
+"""Jitted fleet ensemble engine — `FleetSim.run_many`'s lockstep
+simulator as ONE compiled JAX program (`engine="jit"`, the default).
 
-`fleet_batched.run_batched` advances all trajectories per lockstep round
-but pays NumPy's interpreter tax per round: dozens of temporaries, fancy
-indexing, per-round Python grouping in the draw batchers. This module
-compiles the identical round into a `lax.while_loop` body — trajectory
-state as stacked `(n,)`/`(n, slots)` device arrays, the next-event select
-as an XLA masked min+argmin on every backend (the state is f64, which the
-Pallas `event_select` kernel cannot take), and every draw the engines
-share pre-materialized on device:
+Each lockstep round advances every live trajectory to its own next event
+and applies at most one event per trajectory with masked array ops
+(`run_jit` states the round's contract). The round is a `lax.while_loop`
+body — trajectory state as stacked `(n,)`/`(n, slots)` device arrays, the
+next-event select as an XLA masked min+argmin on every backend (the state
+is f64, which the Pallas `event_select` kernel cannot take), and every
+draw the engines share pre-materialized on device:
 
 * the `(n, slots)` initial-lifetime matrix is `FleetDraws.initial`
   verbatim (chaos hazard transforms already applied on host);
@@ -17,14 +16,14 @@ share pre-materialized on device:
   `LifetimeLaw.sample_from_uniforms` samplers are ported to jittable form
   (GCP truncated-Weibull + 16-round Fig 9 diurnal thinning, AWS inverse
   cumulative hazard on the per-launch-hour grids, Azure inverse
-  exponential), so the keyed-draw contract holds unchanged: all three
-  engines consume identical uniforms and agree exactly on
+  exponential), so the keyed-draw contract holds unchanged: this engine
+  and the event loop consume identical uniforms and agree exactly on
   revocation/replacement counts (tests/test_engine_parity.py);
 * chaos `FaultTimeline` factors become piecewise-constant device tables
   (`factor_tables`) indexed by `searchsorted(boundaries, t)`, and the
   keyed join-hazard uniforms a `(G, n, slots, F)` matrix
   (`join_uniform_matrix`) — all seven scripted scenarios run under this
-  engine bit-identically to the other two.
+  engine bit-identically to the event loop.
 
 Generation pools are *level-paged*: G levels are materialized up front;
 a trajectory whose next revocation needs a deeper replacement chain
@@ -39,8 +38,7 @@ elementwise per trajectory, so results are byte-identical whatever the
 flag or the trajectory sharding (`_shard` splits the trajectory axis
 across `jax.devices()` when more than one is visible —
 `xla_force_host_platform_device_count` in the multidevice CI job).
-docs/DESIGN.md §2 has the state layout; docs/performance.md the
-engine-selection matrix and the `bench_jit_engine` gate.
+docs/DESIGN.md §2 has the state layout; docs/performance.md the spans.
 """
 from __future__ import annotations
 
@@ -58,7 +56,7 @@ from repro.core.perf_model.cluster_model import PSBottleneckModel
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.core.transient.fleet import FleetSim, SimResult
-    from repro.core.transient.fleet_batched import FleetDraws
+    from repro.core.transient.draws import FleetDraws
 
 #: generation levels materialized before the first entry; doubled on
 #: every stall re-entry
@@ -66,8 +64,8 @@ INITIAL_LEVELS = 4
 
 #: widths at or below this run to completion without compaction; above
 #: it the loop exits once the active set halves, the host pages finished
-#: trajectories out (the device analogue of the NumPy engine's shrinking
-#: boolean-mask active set) and re-enters at the next power of two
+#: trajectories out (a shrinking active set) and re-enters at the next
+#: power of two
 COMPACT_MIN = 4096
 
 _GPU_CODES = {"k80": 0, "v100": 1}  # 2 = the p100-family default weight
@@ -150,18 +148,35 @@ def _sample_azure(U, hazard, horizon):
     return jnp.where(t > horizon, jnp.inf, t)
 
 
-def _law_spec(sim: "FleetSim"):
-    """Classify the roster's lifetime laws into one jittable kind plus
-    stacked per-slot parameter arrays. Raises for laws the compiled
-    samplers cannot reproduce (custom providers): those rosters need
-    `engine="batched"`, whose per-key fallback streams handle any law."""
+def _roster_laws(sim: "FleetSim") -> list:
+    return [sim.provider.lifetime_model(region, gpu)
+            for _, gpu, region, _ in sim._roster]
+
+
+def law_family(sim: "FleetSim") -> Optional[str]:
+    """The roster's lifetime-law family with a jittable port — "gcp",
+    "aws" or "azure" — or None when the laws have none (a custom
+    provider's); `FleetSim.run_many` runs such rosters on the event
+    loop."""
     from repro.core.transient.revocation import LifetimeModel
     from repro.providers.aws import PriceSignalLifetime
     from repro.providers.azure import TieredEvictionLifetime
 
-    laws = [sim.provider.lifetime_model(region, gpu)
-            for _, gpu, region, _ in sim._roster]
-    if all(isinstance(l, LifetimeModel) for l in laws):
+    laws = _roster_laws(sim)
+    for kind, law_cls in (("gcp", LifetimeModel),
+                          ("aws", PriceSignalLifetime),
+                          ("azure", TieredEvictionLifetime)):
+        if all(isinstance(l, law_cls) for l in laws):
+            return kind
+    return None
+
+
+def _law_spec(sim: "FleetSim"):
+    """The roster's law family plus stacked per-slot parameter arrays.
+    Raises for laws the compiled samplers cannot reproduce."""
+    kind = law_family(sim)
+    laws = _roster_laws(sim)
+    if kind == "gcp":
         import math
         raw24 = [1.0 - math.exp(-((_GCP_CAP_H / l.lam) ** l.k))
                  for l in laws]
@@ -172,7 +187,7 @@ def _law_spec(sim: "FleetSim"):
             "law_raw24": np.array(raw24),
             "law_code": np.array([_GPU_CODES.get(l.gpu, 2) for l in laws],
                                  np.int32)}
-    if all(isinstance(l, PriceSignalLifetime) for l in laws):
+    if kind == "aws":
         ts_all, cum_all = [], []
         for l in laws:
             grids = [l._grid(kq / 4.0) for kq in range(96)]
@@ -180,15 +195,16 @@ def _law_spec(sim: "FleetSim"):
             cum_all.append(np.stack([c for _, c in grids]))
         return "aws", {"law_ts": np.stack(ts_all),
                        "law_cum": np.stack(cum_all)}
-    if all(isinstance(l, TieredEvictionLifetime) for l in laws):
+    if kind == "azure":
         return "azure", {
             "law_hazard": np.array([l.hazard_per_h for l in laws]),
             "law_horizon": np.array([l.horizon_h for l in laws])}
     raise ValueError(
-        "engine='jit' compiles the provider's lifetime law into the "
+        "run_jit compiles the provider's lifetime law into the "
         "device program and supports the gcp/aws/azure law families; "
         f"this roster's laws ({sorted({type(l).__name__ for l in laws})}) "
-        "have no jittable port — use engine='batched' instead")
+        "have no jittable port — FleetSim.run_many runs such rosters on "
+        "the event loop (engine='event')")
 
 
 # ---------------------------------------------------------------------------
@@ -285,7 +301,8 @@ def _compiled(law_kind: str, handover: bool, graceful: bool,
                 # boundary (the event engine's no-op "resume" heap entry,
                 # never clipped at tmax); effective speed is gated to 0
                 # meanwhile, and otherwise by the quorum tier on the
-                # alive fraction (fleet_batched._degr_factor)
+                # alive fraction: pause (0) below `quorum`,
+                # `shrink_factor` below `shrink_below`, else 1
                 stall_ev = jnp.where(st["stall_t"] > t, st["stall_t"],
                                      P_INF)
                 nb = jnp.minimum(nb, stall_ev)
@@ -321,7 +338,10 @@ def _compiled(law_kind: str, handover: bool, graceful: bool,
             stalled = st["stalled"] | stall_now
             move = (ev | fin) & ~stall_now
             target = jnp.where(ev, jnp.maximum(nxt, t), t_fin)
-            # ---- closed-form advance to `target` (fleet_batched._advance)
+            # ---- closed-form advance to `target`: k checkpoint
+            # boundaries fit in the span, the first at b0/sp, each further
+            # one a full i_c/sp + t_c cycle later; only the last pause can
+            # be partial
             span = jnp.where(move, target - t, 0.0)
             if resilient:
                 # exclusive accrual per span: a stall span is restore
@@ -544,14 +564,39 @@ def run_jit(sim: "FleetSim", total_steps: int, n: int,
             draws: Optional["FleetDraws"] = None, raw: bool = False):
     """Advance `n` trajectories of `sim`'s roster as one jitted program.
 
-    Same contract as `fleet_batched.run_batched` (which documents the
-    round semantics): one `SimResult` per trajectory, exact
-    revocation/replacement parity with both other engines under the
-    shared `FleetDraws`, times/costs to float tolerance. With
-    `raw=True` the per-trajectory stats come back as a dict of arrays
-    instead (same keys as `run_batched(raw=True)`) — the
-    `bench_jit_engine` engine-core measurement and array consumers skip
-    the 65k-`SimResult` Python object construction.
+    Returns one `SimResult` per trajectory, in trajectory order, with
+    `events=[]` (the per-event text log is the one field that cannot be
+    array-typed; `FleetSim.run` keeps it). With `raw=True` the same stats
+    come back as a dict of per-trajectory arrays instead, for array
+    consumers that would otherwise pay for n dataclasses.
+
+    The round contract, which the event loop (`FleetSim.run` under the
+    shared `draws`) meets exactly on revocation/replacement/step counts
+    and to float association order on times and costs:
+
+    * a *slot* is one launch-roster position; a revoked slot whose
+      replacement is pending is dead with a finite join time, and the
+      joined worker inherits the slot's (gpu, region, speed) with its
+      generation bumped;
+    * each round, every live trajectory takes the earliest of its
+      scheduled revocations and joins, the next chaos factor boundary or
+      restore-stall end (pure advancement), and its Eq (4)-style
+      time-to-finish at the current speed (an event exactly at the
+      finish time loses to it), advances to it, and applies at most one
+      event;
+    * the advance walks the sequential checkpoint pauses in closed form
+      (k interval boundaries fit in a span; only the last pause can be
+      partial), where the event loop walks them one at a time;
+    * a revoked chief hands over to the first-joined survivor
+      (`handover`), flushes a checkpoint inside a long enough notice
+      window (`graceful_checkpoint_on_warning`), or rolls the trajectory
+      back to its last checkpoint and books the recompute;
+    * a trajectory is censored at `max_hours`, and freezes where it
+      stands when every worker is dead and nothing is scheduled.
+
+    Raises `ValueError` for a roster whose lifetime law has no jittable
+    port (`law_family` is None); `FleetSim.run_many` sends those to the
+    event loop.
 
     Above `COMPACT_MIN` trajectories the driver pages finished
     trajectories out between `lax.while_loop` entries: the loop hands
@@ -563,7 +608,7 @@ def run_jit(sim: "FleetSim", total_steps: int, n: int,
     bit-identical whatever the compaction (or shard) schedule.
     """
     from repro.core.transient.fleet import SimResult
-    from repro.core.transient.fleet_batched import FleetDraws
+    from repro.core.transient.draws import FleetDraws
 
     if n < 1:
         raise ValueError(f"need at least one trajectory, got {n}")

@@ -22,7 +22,7 @@ _STAGE_MEANS: Dict[str, Tuple[float, float, float]] = {
 _ONDEMAND_DISCOUNT = {"k80": 11.14, "p100": 21.38, "v100": 21.0, "v5e": 25.0}
 BASE_COV = 0.03
 #: 4x higher CoV right after a revocation (Fig 7) — shared with the
-#: batched engine's pre-drawn delay pools (fleet_batched.FleetDraws)
+#: fleet engines' pre-drawn delay pools (draws.FleetDraws)
 POST_REVOCATION_COV = 0.12
 
 

@@ -6,7 +6,7 @@ per-trajectory next event: its time and its column. Fusing the min and the
 tie-broken argmin into one row-blocked pass keeps the event matrix in VMEM
 for a single HBM round-trip; ties resolve to the lowest column index
 (NumPy `argmin` semantics, which the parity contract in docs/DESIGN.md §2
-pins across all three engines). All-masked rows return (`inf`, 0).
+pins across both fleet engines). All-masked rows return (`inf`, 0).
 """
 from __future__ import annotations
 

@@ -30,7 +30,7 @@ def event_select_ref(ev):
     """Oracle for the event-select kernel: per-row masked min + argmin over
     an (n, m) candidate-event matrix (inf = masked), ties broken by lowest
     column index. All-masked rows return (inf, 0) — NumPy argmin semantics,
-    the contract all three fleet engines share (docs/DESIGN.md §2)."""
+    the contract both fleet engines share (docs/DESIGN.md §2)."""
     t = jnp.min(ev, axis=1)
     i = jnp.argmin(ev, axis=1).astype(jnp.int32)
     return t, i

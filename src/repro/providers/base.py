@@ -67,14 +67,15 @@ class LifetimeLaw(abc.ABC):
     SAMPLE_UNIFORMS_K: int = 33
 
     #: Optional vectorized sampler from pre-drawn uniforms — the fleet
-    #: engines' replacement-join path (fleet_batched.FleetDraws). Set to
+    #: engines' replacement-join path (draws.FleetDraws). Set to
     #: a method `(U: (m, K) uniforms, start_hours: (m,) local hours) ->
     #: (m,) lifetimes` that is a *deterministic function of U* with the
     #: same distribution as `sample` (the draw path may differ, e.g.
     #: inverse-transform instead of ziggurat exponentials), vectorized
-    #: over per-sample start hours. Leave as None and the engines fall
+    #: over per-sample start hours. Leave as None and `FleetDraws` falls
     #: back to one counter-based RNG stream per replacement — correct
-    #: for any custom law, just slower.
+    #: for any custom law; `FleetSim.run_many` runs such a law, which has
+    #: no jittable port, on the event loop.
     sample_from_uniforms = None
 
     @abc.abstractmethod

@@ -1,6 +1,6 @@
 """repro.resilience — recovery layer: deterministic retry/backoff,
 quorum degradation tiers, and keyed restore-stall draws shared by the
-live `TransientTrainer` and the three fleet engines (docs/resilience.md,
+live `TransientTrainer` and both fleet engines (docs/resilience.md,
 DESIGN.md §8)."""
 from repro.resilience.policy import (DegradationPolicy, ResilienceConfig,
                                      RetryPolicy, stall_from_uniforms,

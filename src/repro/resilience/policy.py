@@ -27,8 +27,8 @@ import dataclasses
 
 import numpy as np
 
-#: stream tag for restore-retry uniforms (cf. fleet_batched's
-#: _TAG_INITIAL / _TAG_JOIN and the chaos injector tags)
+#: stream tag for restore-retry uniforms (cf. the replacement-level tag
+#: in draws.FleetDraws and the chaos injector tags)
 _TAG_RESTORE = 0x5E11E
 #: stream tag for live-side retry jitter (per holder/op key)
 _TAG_LIVE = 0x5E1FE
@@ -98,9 +98,9 @@ def stall_from_uniforms(retry: RetryPolicy, fail_p: float,
     (``K = retry.max_attempts``): the first K uniforms decide failures
     (``u < fail_p``), the last K supply jitter; the stall is the sum of
     backoff delays over the *leading* run of failures, clamped to the
-    deadline. Pure NumPy float64 — the event and batched engines index
-    it directly and the jit engine ships the materialized pool to
-    device, so all three consume bit-identical delays."""
+    deadline. Pure NumPy float64 — the event loop indexes it directly
+    and the jit engine ships the materialized pool to device, so both
+    consume bit-identical delays."""
     u = np.asarray(u, np.float64)
     k = u.shape[-1] // 2
     u_fail, u_jit = u[..., :k], u[..., k:]

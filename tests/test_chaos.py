@@ -17,10 +17,10 @@ from repro.chaos import (CheckpointOutage, FaultTimeline, LiveFault,
                          list_scenarios, register_scenario, run_scenario,
                          score_history)
 from repro.chaos.injectors import keyed_uniforms
-from repro.chaos.runner import _run_sim
+from repro.chaos.runner import _ens_summary, _run_sim
 from repro.core.transient import fleet_jit
 from repro.core.transient.fleet import FleetSim, SimWorker
-from repro.core.transient.fleet_batched import FleetDraws
+from repro.core.transient.draws import FleetDraws
 
 
 @pytest.fixture(scope="module")
@@ -223,7 +223,7 @@ def test_standalone_run_matches_ensemble_of_one():
         return sim
 
     solo = fresh().run(300_000, max_hours=8.0)
-    ens_b = fresh().run_many(300_000, 1, max_hours=8.0, engine="batched")
+    ens_b = fresh().run_many(300_000, 1, max_hours=8.0)
     ens_e = fresh().run_many(300_000, 1, max_hours=8.0, engine="event")
     for r in (ens_b.results[0], ens_e.results[0]):
         assert r.revocations == solo.revocations
@@ -235,22 +235,30 @@ def test_standalone_run_matches_ensemble_of_one():
 def test_every_scenario_holds_engine_parity(session, name):
     """Per-trajectory revocation/replacement/steps counts must be equal
     and times bit-close on both engines, for every registered scenario —
-    and the ground-truth hash (truth + transformed lifetime matrix) must
-    not depend on the engine choice."""
+    and the scorecard's ensemble summaries must equal the event loop's."""
     sc = get_scenario(name)
-    a = _run_sim(session, sc, "batched", 4, seed=1)
-    b = _run_sim(session, sc, "event", 4, seed=1)
-    assert a["parity"]["counts_equal"] and b["parity"]["counts_equal"]
+    a = _run_sim(session, sc, 4, seed=1)
+    assert a["parity"]["counts_equal"]
     assert a["parity"]["time_max_rel_err"] < 1e-9
-    assert b["parity"]["time_max_rel_err"] < 1e-9
-    assert a["truth_hash"] == b["truth_hash"]
-    assert a["faulted"] == b["faulted"] and a["baseline"] == b["baseline"]
+    # the card's ensembles (jit) summarize exactly as the event loop's
+    event = {}
+    for key, chaos in (("faulted", True), ("baseline", False)):
+        sim, n_steps = session._fleet_sim(
+            n_workers=sc.n_workers, gpu=sc.gpu, region=sc.region,
+            steps=sc.total_steps, seed=1, handover=sc.handover,
+            provider=sc.provider)
+        if chaos:
+            sim.chaos = sc.timeline(sim._roster, seed=1)
+        event[key] = _ens_summary(sim.run_many(
+            n_steps, 4, max_hours=sc.max_hours, engine="event"))
+    assert a["faulted"] == event["faulted"]
+    assert a["baseline"] == event["baseline"]
 
 
 def test_dead_ps_stalls_for_the_window(session):
     """Capacity 0 for an hour must cost the run ~the whole window (plus
     nothing else: no revocations are scripted)."""
-    card = _run_sim(session, get_scenario("dead_ps"), "batched", 4, seed=0)
+    card = _run_sim(session, get_scenario("dead_ps"), 4, seed=0)
     assert card["impact"]["extra_time_s"] == pytest.approx(3600.0, abs=600)
     # no scripted hazard — only stock lifetimes that now fire because the
     # stalled run ends later can add the odd revocation

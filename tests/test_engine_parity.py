@@ -1,9 +1,9 @@
-"""Three-engine differential parity harness — the lock on `engine="jit"`.
+"""Differential parity harness — the lock on `run_many`'s jit engine.
 
 `fleet_jit.run_jit` compiles the lockstep rounds into one jitted JAX
-program; this file pins its contract against the other two engines: for
+program; this file pins its contract against the event-loop oracle: for
 ANY (provider, fleet shape, horizon, compression, chaos scenario, seed)
-all three must report exactly equal per-trajectory
+both must report exactly equal per-trajectory
 revocation/replacement/step counts — they consume the same `FleetDraws`
 uniform streams — and times/costs within float association tolerance.
 
@@ -13,8 +13,7 @@ Three layers:
   distinct code path (stock-chief step loss, AWS graceful window,
   no-replace frozen fleets, single-slot fleets, compression in the PS
   cap, deep replacement chains that force jit pool paging);
-* a `hypothesis` fuzz sweep over the same axes (deterministic stub when
-  the real package is absent — conftest.py);
+* a `hypothesis` fuzz sweep over the same axes;
 * schedule-invariance regressions: results must be byte-identical
   whatever the `jax_enable_x64` global flag and whatever compaction
   schedule the host driver happens to pick, and exact under trajectory
@@ -28,7 +27,7 @@ from hypothesis import given, settings, strategies as st
 from repro.chaos.scenarios import get_scenario, list_scenarios
 from repro.core.transient import fleet_jit
 from repro.core.transient.fleet import FleetSim, SimWorker
-from repro.core.transient.fleet_batched import FleetDraws
+from repro.core.transient.draws import FleetDraws
 from repro.core.transient.fleet_jit import run_jit
 from repro.providers import get_provider
 
@@ -45,7 +44,7 @@ def _mk_sim(seed=0, provider="gcp", region="us-central1", gpu="v100",
                     grad_compression=grad_compression)
 
 
-def _assert_parity(mk, run_args, engines=("batched", "event")):
+def _assert_parity(mk, run_args, engines=("event",)):
     """run_many on the jit engine and every engine in `engines` from
     identical fresh sims; counts must be exactly equal, continuous stats
     equal up to float association order."""
@@ -143,7 +142,7 @@ def test_fuzz_three_engine_parity(cell, n_workers, horizon, compression,
 def test_resilience_three_engine_parity(quorum):
     """Recovery semantics ride the same contract: keyed restore-retry
     stalls after stock-chief revocations and quorum pause windows must
-    reproduce bit-for-bit on all three engines (`paused_s` /
+    reproduce bit-for-bit on both engines (`paused_s` /
     `restore_delay_s` asserted inside `_assert_parity`), and arming a
     `ResilienceConfig` must not perturb counts or completion times'
     agreement."""
@@ -178,7 +177,7 @@ def test_jit_parity_every_chaos_scenario(name):
     """All seven scripted fault timelines run under the jit engine —
     fault-window factors as piecewise-constant device tables, keyed
     join-hazard uniforms as a pool matrix — bit-identically to the
-    other two engines."""
+    event loop."""
     sc = get_scenario(name)
     region = sc.region or get_provider(sc.provider).default_region
 
@@ -194,9 +193,8 @@ def test_jit_parity_every_chaos_scenario(name):
 @pytest.mark.slow
 def test_chaos_scorecard_truth_hash_engine_and_x64_independent():
     """The scorecard a chaos run emits — truth spans, `truth_hash`,
-    ensemble summaries — must be byte-identical whichever engine scored
-    it and whatever the global `jax_enable_x64` flag (the latent
-    nondeterminism this PR pins down)."""
+    ensemble summaries — must be byte-identical whatever the global
+    `jax_enable_x64` flag, with its jit-vs-event parity probe exact."""
     from repro.api import Session
     from repro.chaos.runner import _run_sim
 
@@ -207,11 +205,10 @@ def test_chaos_scorecard_truth_hash_engine_and_x64_independent():
     try:
         for x64 in (False, True):
             jax.config.update("jax_enable_x64", x64)
-            for eng in ("batched", "jit"):
-                cards[(eng, x64)] = _run_sim(ses, sc, eng, 8, seed=1)
+            cards[x64] = _run_sim(ses, sc, 8, seed=1)
     finally:
         jax.config.update("jax_enable_x64", prev)
-    ref_card = cards[("batched", False)]
+    ref_card = cards[False]
     for key, card in cards.items():
         assert card["truth_hash"] == ref_card["truth_hash"], key
         assert card["truth"] == ref_card["truth"], key
@@ -270,15 +267,15 @@ def test_results_independent_of_compaction_schedule():
 @pytest.mark.skipif(len(jax.devices()) < 2,
                     reason="needs >1 device "
                            "(xla_force_host_platform_device_count)")
-def test_sharded_pad_rows_match_batched_oracle():
+def test_sharded_pad_rows_match_event_oracle():
     """Under trajectory sharding, n not divisible by the device count
-    pads the state with inert rows; parity with the NumPy engine proves
+    pads the state with inert rows; parity with the event loop proves
     the pads never leak into real trajectories."""
     n = 13                       # 13 % 4 != 0 on the 4-device CI job
     def mk():
         return _mk_sim(seed=4, region="europe-west1", gpu="k80",
                        n_workers=4)
-    _assert_parity(mk, (150_000, n, 48.0, 0.0), engines=("batched",))
+    _assert_parity(mk, (150_000, n, 48.0, 0.0))
 
 
 def test_run_jit_rejects_empty_ensemble():
@@ -286,9 +283,9 @@ def test_run_jit_rejects_empty_ensemble():
         run_jit(_mk_sim(), 1000, 0)
 
 
-def test_unsupported_law_family_points_at_batched():
-    """A roster whose lifetime law has no jittable port must fail with
-    actionable advice, not compile garbage."""
+def test_unsupported_law_family_points_at_event():
+    """A roster whose lifetime law has no jittable port must fail in
+    `run_jit` with actionable advice, not compile garbage."""
     class _OddLaw:
         pass
 
@@ -302,5 +299,7 @@ def test_unsupported_law_family_points_at_batched():
 
     sim = _mk_sim()
     sim.provider = _OddProvider()
-    with pytest.raises(ValueError, match="no jittable port"):
+    assert fleet_jit.law_family(sim) is None
+    with pytest.raises(ValueError,
+                       match="no jittable port.*event loop"):
         run_jit(sim, 1000, 4)

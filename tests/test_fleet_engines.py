@@ -1,9 +1,11 @@
-"""Lockstep batched fleet engine tests: batched-vs-event parity (exact
-revocation/replacement counts under the shared `FleetDraws` streams, KS
-agreement on time/cost distributions), censoring, all three providers
-including the AWS 2-minute warning window, `grad_compression` in the
-simulated PS term, the `score="sim"` planner, and the vectorized
-`ps_queue_sim` against a pinned copy of the retired heap loop."""
+"""Fleet engine tests: `run_many`'s default (jit) engine against the
+event-loop oracle (exact revocation/replacement counts under the shared
+`FleetDraws` streams, KS agreement on time/cost distributions),
+censoring, all three providers including the AWS 2-minute warning
+window, custom lifetime laws on the event loop, `grad_compression` in
+the simulated PS term, the `score="sim"` planner and the Session paths
+that reach the jit engine, and the vectorized `ps_queue_sim` against a
+pinned copy of the retired heap loop."""
 import heapq
 import math
 
@@ -14,8 +16,12 @@ from repro.core.ps_async import ps_queue_sim
 from repro.core.perf_model.cluster_model import (PS_NET_BYTES_PER_S,
                                                  PSBottleneckModel)
 from repro.core.scheduler import plan_launch
+from repro.core.transient import fleet_jit
+from repro.core.transient.draws import FleetDraws
 from repro.core.transient.fleet import FleetEnsemble, FleetSim, SimWorker
-from repro.core.transient.fleet_batched import FleetDraws, run_batched
+from repro.providers import registry
+from repro.providers.base import LifetimeLaw
+from repro.providers.gcp import GCPPreemptible
 
 
 def _mk_sim(seed=0, provider="gcp", region="us-central1", gpu="v100",
@@ -32,9 +38,20 @@ def _mk_sim(seed=0, provider="gcp", region="us-central1", gpu="v100",
 
 
 def _both(sim_kwargs, run_args):
-    a = _mk_sim(**sim_kwargs).run_many(*run_args, engine="batched")
+    a = _mk_sim(**sim_kwargs).run_many(*run_args)
     b = _mk_sim(**sim_kwargs).run_many(*run_args, engine="event")
     return a, b
+
+
+def _count_run_jit(monkeypatch):
+    """Trajectory counts of every `fleet_jit.run_jit` call from here on."""
+    calls, real = [], fleet_jit.run_jit
+
+    def counted(sim, total_steps, n, *args, **kwargs):
+        calls.append(n)
+        return real(sim, total_steps, n, *args, **kwargs)
+    monkeypatch.setattr(fleet_jit, "run_jit", counted)
+    return calls
 
 
 def _ks_distance(a, b):
@@ -56,8 +73,8 @@ def test_engines_agree_exactly_on_shared_draws(provider, region, gpu,
     """Both engines consume the same `FleetDraws` streams, so identical
     pre-drawn lifetimes and replacement chains must give EXACT
     revocation/replacement counts per trajectory; times/costs agree up
-    to float association order (the batched stepper walks checkpoint
-    pauses in closed form, the event loop incrementally)."""
+    to float association order (the jit round walks checkpoint pauses
+    in closed form, the event loop incrementally)."""
     kw = dict(seed=3, provider=provider, region=region, gpu=gpu, sp=4.56,
               handover=handover)
     a, b = _both(kw, (400_000, 24, 60.0, 7.0))
@@ -124,13 +141,12 @@ def test_aws_warning_window_graceful_checkpoint():
     assert all(r.lost_steps == 0 for r in b.results)
     gcp = _mk_sim(seed=2, region="europe-west1", gpu="k80", sp=4.56,
                   n_workers=6, handover=False, i_c=1000, t_c=60.0)
-    ens = gcp.run_many(400_000, 32, max_hours=80.0, engine="batched")
+    ens = gcp.run_many(400_000, 32, max_hours=80.0)
     assert sum(r.lost_steps for r in ens.results) > 0
 
 
 def test_batched_censoring_reported():
-    ens = _mk_sim(seed=0).run_many(10_000_000, 8, max_hours=0.5,
-                                   engine="batched")
+    ens = _mk_sim(seed=0).run_many(10_000_000, 8, max_hours=0.5)
     assert isinstance(ens, FleetEnsemble)
     assert ens.stats.finished == 0
     assert all(r.steps_done < 10_000_000 for r in ens.results)
@@ -163,8 +179,7 @@ def test_engines_agree_on_finished_for_awkward_step_counts():
     finished=0 for completed runs)."""
     for total in (12345, 4321, 99991):
         kw = dict(seed=0, sp=3.7, n_workers=4, i_c=997, t_c=1.3)
-        a = _mk_sim(**kw).run_many(total, 6, max_hours=1000.0,
-                                   engine="batched")
+        a = _mk_sim(**kw).run_many(total, 6, max_hours=1000.0)
         b = _mk_sim(**kw).run_many(total, 6, max_hours=1000.0,
                                    engine="event")
         assert a.stats.finished == b.stats.finished == 6
@@ -172,9 +187,61 @@ def test_engines_agree_on_finished_for_awkward_step_counts():
             [r.steps_done for r in b.results]
 
 
-def test_run_many_rejects_unknown_engine():
+@pytest.mark.parametrize("engine", ["warp", "batched"])
+def test_run_many_rejects_unknown_engine(engine):
     with pytest.raises(ValueError, match="unknown engine"):
-        _mk_sim().run_many(1000, 2, engine="warp")
+        _mk_sim().run_many(1000, 2, engine=engine)
+
+
+class _CappedExponential(LifetimeLaw):
+    """A custom law: exponential lifetimes, survived past 24 h. It has
+    no uniform-block sampler and no jittable port."""
+
+    def __init__(self, hazard_per_h: float):
+        self.hazard_per_h = hazard_per_h
+
+    def cdf(self, t_hours):
+        t = np.minimum(np.asarray(t_hours, float), 24.0)
+        return 1.0 - np.exp(-self.hazard_per_h * t)
+
+    def prob_revoked_within(self, t_hours):
+        return float(self.cdf(t_hours))
+
+    def sample(self, rng, n=1, start_hour=0.0):
+        t = rng.exponential(1.0 / self.hazard_per_h, int(n))
+        return np.where(t > 24.0, np.inf, t)
+
+    def mean_time_to_revocation(self):
+        return 1.0 / self.hazard_per_h
+
+
+class _CustomMarket(GCPPreemptible):
+    name = "custom-exp"
+    display_name = "custom exponential market"
+
+    def lifetime_model(self, region, gpu):
+        self.check_offered(region, gpu)
+        return _CappedExponential(0.3)
+
+
+def test_custom_law_ensemble_runs_on_the_event_loop(monkeypatch):
+    """A registered provider whose law has no jittable port runs its
+    ensembles through `run_many` on the event loop, with no option set,
+    and equals `run_many(engine="event")`."""
+    monkeypatch.setattr(registry, "_REGISTRY", dict(registry._REGISTRY))
+    registry.register_provider(_CustomMarket())
+    calls = _count_run_jit(monkeypatch)
+    kw = dict(seed=3, provider="custom-exp", sp=4.56, n_workers=4)
+    assert fleet_jit.law_family(_mk_sim(**kw)) is None
+    a, b = _both(kw, (400_000, 12, 60.0, 5.0))
+    assert calls == []
+    assert sum(r.revocations for r in a.results) > 0
+    assert [r.revocations for r in a.results] == \
+        [r.revocations for r in b.results]
+    assert [r.total_time_s for r in a.results] == \
+        [r.total_time_s for r in b.results]
+    assert [r.monetary_cost for r in a.results] == \
+        [r.monetary_cost for r in b.results]
 
 
 def test_single_run_unchanged_by_engine_dispatch():
@@ -216,13 +283,15 @@ def test_session_simulate_engine_and_compression(tmp_path):
     from repro.api import Session
     s = Session.from_arch("qwen3-1.7b", total_steps=300,
                           checkpoint_interval=100, zero1=False)
-    ens_b = s.simulate(n_workers=2, gpu="v100", steps=300, seed=0,
-                       samples=6, engine="batched")
-    ens_e = s.simulate(n_workers=2, gpu="v100", steps=300, seed=0,
-                       samples=6, engine="event")
+    kw = dict(n_workers=2, gpu="v100", steps=300, seed=0)
+    ens_b = s.simulate(samples=6, **kw)
+    sim, n_steps = s._fleet_sim(**kw)
+    ens_e = sim.run_many(n_steps, 6, engine="event")
     np.testing.assert_allclose(
         [r.total_time_s for r in ens_b.results],
         [r.total_time_s for r in ens_e.results], rtol=1e-9)
+    assert [r.revocations for r in ens_b.results] == \
+        [r.revocations for r in ens_e.results]
     comp = Session.from_arch("qwen3-1.7b", total_steps=300,
                              checkpoint_interval=100, zero1=False,
                              grad_compression="int8")
@@ -266,18 +335,23 @@ def test_plan_launch_sim_score_golden_and_fields():
 
 
 def test_plan_launch_sim_engines_agree():
-    _, pb = plan_launch("v100", 2, 15.61, n_w=200_000, i_c=4000, t_c=3.84,
-                        hours=[6], seed=1, samples=24, score="sim",
-                        engine="batched")
-    _, pe = plan_launch("v100", 2, 15.61, n_w=200_000, i_c=4000, t_c=3.84,
-                        hours=[6], seed=1, samples=24, score="sim",
-                        engine="event")
-    for a, b in zip(pb, pe):
-        assert (a.region, a.launch_hour) == (b.region, b.launch_hour)
-        assert a.expected_revocations == b.expected_revocations
-        assert a.expected_time_s == pytest.approx(b.expected_time_s,
+    """Every sim-scored cell equals the event loop's ensemble of the
+    same fleet, seed and launch hour."""
+    _, plans = plan_launch("v100", 2, 15.61, n_w=200_000, i_c=4000,
+                           t_c=3.84, hours=[6], seed=1, samples=24,
+                           score="sim", max_sim_hours=48.0)
+    assert len(plans) > 1
+    for p in plans:
+        sim = _mk_sim(seed=1, region=p.region, gpu="v100", sp=15.61,
+                      n_workers=2)
+        sim.price_of = {"v100": sim.provider.price("v100")}
+        st = sim.run_many(200_000, 24, max_hours=48.0,
+                          start_hour=float(p.launch_hour),
+                          engine="event").stats
+        assert p.expected_revocations == st.revocations_mean
+        assert p.expected_time_s == pytest.approx(st.time_mean_s,
                                                   rel=1e-9)
-        assert a.expected_cost == pytest.approx(b.expected_cost, rel=1e-9)
+        assert p.expected_cost == pytest.approx(st.cost_mean, rel=1e-9)
 
 
 def test_plan_launch_sim_rejects_bad_score():
@@ -302,6 +376,28 @@ def test_session_plan_sim_score():
                          hours=[0, 12], samples=16, score="sim", n_ps=1)
     assert explicit.expected_time_s == best.expected_time_s
     assert explicit.expected_cost == best.expected_cost
+
+
+def test_session_fleet_paths_reach_run_jit(monkeypatch):
+    """`Session.plan(score="sim")`, `Session.simulate(samples>1)` and
+    `Session.chaos` run their ensembles on the jit engine by default;
+    `simulate(samples=1)` stays the single `FleetSim.run` trajectory."""
+    from repro.api import Session
+    from repro.chaos.runner import PARITY_SAMPLES
+    s = Session.from_arch("qwen3-1.7b", total_steps=300,
+                          checkpoint_interval=100, zero1=False)
+    calls = _count_run_jit(monkeypatch)
+    s.plan(gpu="v100", n_workers=2, steps=300, hours=[0],
+           region="us-central1", samples=6, score="sim")
+    assert calls == [6]
+    s.simulate(n_workers=2, gpu="v100", steps=300, samples=5)
+    assert calls == [6, 5]
+    s.simulate(n_workers=2, gpu="v100", steps=300)
+    assert calls == [6, 5]
+    card = s.chaos("dead_ps", live=False, samples=4)
+    # faulted and baseline ensembles, then the parity probe's jit half
+    assert calls == [6, 5, 4, 4, PARITY_SAMPLES]
+    assert card["scenarios"]["dead_ps"]["sim"]["parity"]["counts_equal"]
 
 
 def test_cli_plan_forwards_n_ps(capsys):
@@ -432,18 +528,4 @@ def test_fleet_draws_deterministic_and_order_independent():
     assert d1.replacement_delay(0, 0, 1) == b
     la = d1.join_lifetime(5, 2, 1, 13.25)
     assert d2.join_lifetime(5, 2, 1, 13.25) == la
-    # batch and scalar paths agree bit-for-bit
-    lb = d2.join_lifetimes_batch(np.array([5]), np.array([2]),
-                                 np.array([1]), np.array([13.25]))
-    assert float(lb[0]) == la
 
-
-def test_run_batched_matches_run_many_wrapper():
-    sim = _mk_sim(seed=7)
-    results = run_batched(sim, 100_000, 6, max_hours=100.0, start_hour=3.0)
-    ens = _mk_sim(seed=7).run_many(100_000, 6, max_hours=100.0,
-                                   start_hour=3.0, engine="batched")
-    assert [r.total_time_s for r in results] == \
-        [r.total_time_s for r in ens.results]
-    assert [r.revocations for r in results] == \
-        [r.revocations for r in ens.results]

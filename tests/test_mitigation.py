@@ -387,15 +387,11 @@ def test_bench_regression_gate(tmp_path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
 
-    def write(name, speedup, engine_speedup=12.0, jit_speedup=6.0):
+    def write(name, speedup):
         p = tmp_path / name
         p.write_text(json.dumps({
             "planner_grid": {"speedup": speedup, "batched_s": 0.01},
-            "ensemble": {"traj_per_s": 100.0},
-            "batched_engine": {"speedup": engine_speedup,
-                               "traj_per_s": 50000.0},
-            "jit_engine": {"speedup": jit_speedup, "traj_per_s": 50000.0,
-                           "devices": 1}}))
+            "ensemble": {"traj_per_s": 100.0}}))
         return str(p)
 
     base = write("base.json", 50.0)
@@ -403,25 +399,10 @@ def test_bench_regression_gate(tmp_path):
                      "--current", write("ok.json", 45.0)]) == 0
     assert mod.main(["--baseline", base,                      # >20% slower
                      "--current", write("bad.json", 30.0)]) == 1
-    # the lockstep engine has an absolute floor on top of the relative one
+    # a current file missing the planner-grid metric fails the gate
+    (tmp_path / "nogrid.json").write_text(json.dumps({"ensemble": {}}))
     assert mod.main(["--baseline", base,
-                     "--current", write("eng.json", 45.0, 9.0)]) == 1
-    assert mod.main(["--baseline", base,
-                     "--current", write("eng2.json", 45.0, 10.5),
-                     "--min-engine-speedup", "10.0"]) == 0
-    # ... and so does the jit engine (default floor 5x)
-    assert mod.main(["--baseline", base,
-                     "--current", write("jit.json", 45.0,
-                                        jit_speedup=4.5)]) == 1
-    assert mod.main(["--baseline", base,
-                     "--current", write("jit2.json", 45.0,
-                                        jit_speedup=5.5),
-                     "--min-jit-speedup", "5.0"]) == 0
-    # a current file missing an engine metric fails the gate
-    (tmp_path / "noeng.json").write_text(json.dumps({
-        "planner_grid": {"speedup": 50.0}, "ensemble": {}}))
-    assert mod.main(["--baseline", base,
-                     "--current", str(tmp_path / "noeng.json")]) == 1
+                     "--current", str(tmp_path / "nogrid.json")]) == 1
     (tmp_path / "empty.json").write_text("{}")
     assert mod.main(["--baseline", str(tmp_path / "empty.json"),
                      "--current", base]) == 1

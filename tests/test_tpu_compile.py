@@ -169,9 +169,9 @@ def test_fleet_engine_program_fits(one_chip, monkeypatch):
         return call
 
     monkeypatch.setattr(fleet_jit, "_compiled", first_entry)
-    _, simulate = SMOKE.fleet_simulate()
+    _, simulate, _ = SMOKE.fleet_simulate()
     with pytest.raises(Entered):
-        simulate(samples=SMOKE.PARITY_N, engine="jit")
+        simulate(samples=SMOKE.PARITY_N)
     with jax.enable_x64(True):
         st, ar = _on(one_chip, (seen["st"], seen["ar"]))
         compiled = seen["fn"].lower(st, ar).compile()
